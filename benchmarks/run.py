@@ -816,6 +816,8 @@ def main() -> None:
                     help="comma-list: logreg,nn,lag,hierarchical,"
                          "ablations,roofline,cada,sim")
     args = ap.parse_args()
+    from repro.launch.cache import init_compile_cache
+    init_compile_cache()
     full = args.full
     only = set(args.only.split(",")) if args.only else None
 

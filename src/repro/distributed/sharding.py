@@ -52,11 +52,6 @@ class FlatSharding:
         return tuple(a for a in self.axes if a != self.waxis)
 
     @property
-    def plane_axes(self) -> tuple:
-        """Every mesh axis a worker plane touches (rows + columns)."""
-        return tuple(dict.fromkeys((self.waxis,) + self.col_axes))
-
-    @property
     def shards(self) -> int:
         """State-shard count = required divisor of ``FlatLayout.n_flat``."""
         s = 1
@@ -73,16 +68,6 @@ class FlatSharding:
         return P(self.waxis, spec_dim(self.col_axes))
 
     def constrain_server(self, x):
-        # STAGED pin: the pinned jax 0.4.37's SPMD partitioner MISCOMPILES
-        # the direct reshard of a freshly packed (concatenate + pad) 1-D
-        # buffer to a sharded layout on meshes with more than one
-        # non-trivial axis — the values come back permuted (norms are
-        # permutation-invariant, so only position-sensitive consumers like
-        # unpack see it; pinned by the pod-mesh trainer test). Pinning the
-        # pack product to an explicit replicated layout FIRST and then to
-        # the shard spec compiles correctly on every mesh we can force.
-        x = jax.lax.with_sharding_constraint(
-            x, NamedSharding(self.mesh, P(*(None,) * x.ndim)))
         return jax.lax.with_sharding_constraint(
             x, NamedSharding(self.mesh, self.server_spec()))
 

@@ -56,7 +56,7 @@ from repro.core.comm import (CommState, comm_round, comm_state_specs,
                              strategy_for)
 from repro.core.rules import CommRule
 from repro.kernels import ops as kops
-from repro.launch.mesh import DATA, POD, partial_auto_shard_map
+from repro.launch.mesh import DATA, POD
 from repro.models.config import ModelConfig
 from repro.models.model import abstract_params, init_params, lm_loss
 from repro.distributed.sharding import (FlatSharding, param_pspecs,
@@ -328,6 +328,17 @@ def init_train_state(cfg: ModelConfig, hp: TrainHParams, m: int, rng,
     )
 
 
+def place_train_state(state: DistTrainState, mesh, sspecs
+                      ) -> DistTrainState:
+    """Commit ``state`` to the shardings ``jit_train_step`` compiled for
+    (``sspecs`` is its second result). The jitted step refuses committed
+    arguments laid out otherwise, and arrays made under ``jax.set_mesh``
+    are committed replicated."""
+    return jax.device_put(state, jax.tree.map(
+        lambda s: to_named(mesh, s), sspecs,
+        is_leaf=lambda x: isinstance(x, P)))
+
+
 def abstract_train_state(cfg: ModelConfig, hp: TrainHParams, m: int,
                          shards: int = 1):
     return jax.eval_shape(
@@ -391,8 +402,9 @@ def make_pod_vgrads(cfg: ModelConfig, hp: TrainHParams, mesh):
                               is_leaf=lambda x: isinstance(x, P))
 
     def _shardmapped(f, in_specs):
-        return partial_auto_shard_map(f, mesh, in_specs,
-                                      (P(POD), P(POD)), (POD,))
+        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                             out_specs=(P(POD), P(POD)), axis_names={POD},
+                             check_vma=False)
 
     def make(worker_grad):
         def body_bcast(params, batch):
@@ -758,16 +770,14 @@ def jit_train_step(cfg: ModelConfig, mesh, hp: TrainHParams):
     # effective (and stable) mechanism. The pod-manual shard_map is opt-in:
     # it crashes the XLA partitioner when combined with FSDP param specs
     # (spmd_partitioner_util.cc:504 CHECK), so it is enabled only for
-    # non-FSDP configs. Env switches for §Perf ablations.
-    import os as _os
+    # non-FSDP configs.
     use_podmap = (waxis == POD
-                  and not _os.environ.get("REPRO_NO_PODMAP")
                   and not (hp.fsdp
                            or (hp.fsdp is None and wants_fsdp(cfg, mesh))))
     vgrad_factory = make_pod_vgrads(cfg, hp, mesh) if use_podmap else None
 
     def micro_constrain(mb):
-        if waxis != POD or _os.environ.get("REPRO_NO_MICROCONSTRAIN"):
+        if waxis != POD:
             return mb  # single-pod: the worker IS the data group
 
         def spec_for(key, ndim):

@@ -5,18 +5,24 @@ parameter vector: the Adam/AMSGrad update (eqs. 2a-2c) plus CADA's two norm
 reductions (the rule's RHS needs ||θ^{k+1}-θ^k||², the LHS needs
 ||fresh-stale||²). A naive jnp implementation makes ~9 separate HBM passes
 over {θ, h, v, v̂, ∇}; both kernels below make exactly ONE pass, with the
-scalar reductions accumulated in fp32 VMEM.
+scalar reductions accumulated in fp32.
 
-TPU adaptation notes (DESIGN.md §6):
+TPU adaptation notes:
   * parameters are flattened and tiled into (BLOCK_ROWS, 128) VMEM blocks —
     lane dim 128, sublane a multiple of 8, so the VPU is fully utilized;
-  * the reduction output is a (1, 1) fp32 block revisited by every grid step
-    (TPU grid is sequential), initialized at step 0 — the standard Pallas
-    accumulation pattern, no atomics needed (vs. the CUDA grid-reduce);
+  * each block's partial sum is reduced to a scalar and added to an fp32
+    accumulator that lives in SMEM (Mosaic cannot store scalars to VMEM):
+    a whole-array (1, 1) output for the single-plane kernels, a whole-array
+    (M,) output indexed by the worker row for the batched ones. The
+    accumulator is initialized at the first block and revisited by every
+    later one, so the block axis is declared ``arbitrary`` (sequential) —
+    the standard Pallas accumulation pattern, no atomics needed;
+  * scalar inputs (the learning rate) ride in SMEM too;
   * moments are carried in fp32 even when θ is bf16 (matches optim/adam.py).
 
-Validated in ``interpret=True`` mode against ``ref.py`` (see
-tests/test_kernels.py for the shape/dtype sweep).
+Validated in ``interpret=True`` mode against ``ref.py`` (tests/test_kernels.py
+sweeps shapes and dtypes) and compiled for a described TPU v5e at real
+widths (tests/test_tpu_compile.py).
 """
 from __future__ import annotations
 
@@ -25,10 +31,19 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 BLOCK_ROWS = 256          # (256, 128) fp32 blocks = 128 KiB/operand in VMEM
 BLOCK = BLOCK_ROWS * LANES
+
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)   # whole array, scalar memory
+
+
+def _sequential(n_axes: int):
+    """Every grid axis ``arbitrary``: the SMEM accumulators are revisited
+    across the grid, so its steps must run in order on one core."""
+    return pltpu.CompilerParams(dimension_semantics=("arbitrary",) * n_axes)
 
 
 def _amsgrad_kernel(theta_ref, h_ref, vhat_ref, grad_ref, lr_ref,
@@ -51,7 +66,7 @@ def _amsgrad_kernel(theta_ref, h_ref, vhat_ref, grad_ref, lr_ref,
     h = (b1 * h32 + (1.0 - b1) * g).astype(h_out.dtype)
     v = b2 * vh32 + (1.0 - b2) * g * g
     vhat = jnp.maximum(v, vh32).astype(vhat_out.dtype)
-    upd = (-lr_ref[0] * h.astype(jnp.float32)
+    upd = (-lr_ref[0, 0] * h.astype(jnp.float32)
            / jnp.sqrt(eps + vhat.astype(jnp.float32)))
 
     theta = theta_ref[...]
@@ -81,22 +96,21 @@ def fused_amsgrad_flat(theta, h, vhat, grad, lr, *, b1=0.9, b2=0.999,
     nb = n // BLOCK
     shape2d = (nb * BLOCK_ROWS, LANES)
     t2, h2, vh2, g2 = (a.reshape(shape2d) for a in (theta, h, vhat, grad))
-    lr_arr = jnp.asarray([lr], jnp.float32)
+    lr_arr = jnp.asarray(lr, jnp.float32).reshape(1, 1)
 
     spec = pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0))
     outs = pl.pallas_call(
         partial(_amsgrad_kernel, b1=b1, b2=b2, eps=eps),
         grid=(nb,),
-        in_specs=[spec, spec, spec, spec,
-                  pl.BlockSpec((1,), lambda i: (0,))],
-        out_specs=(spec, spec, spec,
-                   pl.BlockSpec((1, 1), lambda i: (0, 0))),
+        in_specs=[spec, spec, spec, spec, _SMEM],
+        out_specs=(spec, spec, spec, _SMEM),
         out_shape=(
             jax.ShapeDtypeStruct(shape2d, theta.dtype),
             jax.ShapeDtypeStruct(shape2d, h.dtype),
             jax.ShapeDtypeStruct(shape2d, vhat.dtype),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ),
+        compiler_params=_sequential(1),
         interpret=interpret,
     )(t2, h2, vh2, g2, lr_arr)
     t_new, h_new, vh_new, sq = outs
@@ -107,50 +121,52 @@ def _batched_diff_sq_kernel(a_ref, b_ref, out_ref):
     """Partial Σ_j (a_mj − b_mj)² for ONE worker row, accumulated across the
     inner (sequential) block grid axis — all M CADA rule LHS norms in a
     single pass over the two (M, n) planes."""
+    i = pl.program_id(0)
     d = a_ref[...].astype(jnp.float32) - b_ref[...].astype(jnp.float32)
     blk = jnp.sum(d * d)
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
-        out_ref[0, 0] = 0.0
+        out_ref[i] = 0.0
 
-    out_ref[0, 0] += blk
+    out_ref[i] += blk
 
 
 def batched_diff_sq_norm_flat(a, b, *, interpret=False):
     """(M,) per-worker ||a_m − b_m||² over (M, n) pre-flattened planes.
 
     The grid is (M, n/BLOCK) with the block axis innermost: the TPU grid is
-    sequential, so each worker's (1, 1) accumulator is initialized at its
-    first block and revisited — the same pattern as the unbatched kernels,
-    just with a second grid axis for the worker rows.
+    sequential, so each worker's entry of the (M,) SMEM accumulator is
+    initialized at its first block and revisited — the same pattern as the
+    unbatched kernels, just with a second grid axis for the worker rows.
     """
     m, n = a.shape
     assert n % BLOCK == 0, f"flat width {n} not a multiple of {BLOCK}"
     nb = n // BLOCK
     shape3d = (m, nb * BLOCK_ROWS, LANES)
-    spec = pl.BlockSpec((1, BLOCK_ROWS, LANES), lambda i, j: (i, j, 0))
-    out = pl.pallas_call(
+    spec = pl.BlockSpec((None, BLOCK_ROWS, LANES), lambda i, j: (i, j, 0))
+    return pl.pallas_call(
         _batched_diff_sq_kernel,
         grid=(m, nb),
         in_specs=[spec, spec],
-        out_specs=pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, 1), jnp.float32),
+        out_specs=_SMEM,
+        out_shape=jax.ShapeDtypeStruct((m,), jnp.float32),
+        compiler_params=_sequential(2),
         interpret=interpret,
     )(a.reshape(shape3d), b.reshape(shape3d))
-    return out[:, 0]
 
 
 def _batched_sq_kernel(a_ref, out_ref):
     """Partial Σ_j a_mj² for one worker row (single-operand variant)."""
+    i = pl.program_id(0)
     v = a_ref[...].astype(jnp.float32)
     blk = jnp.sum(v * v)
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
-        out_ref[0, 0] = 0.0
+        out_ref[i] = 0.0
 
-    out_ref[0, 0] += blk
+    out_ref[i] += blk
 
 
 def batched_sq_norm_flat(a, *, interpret=False):
@@ -159,16 +175,16 @@ def batched_sq_norm_flat(a, *, interpret=False):
     assert n % BLOCK == 0, f"flat width {n} not a multiple of {BLOCK}"
     nb = n // BLOCK
     shape3d = (m, nb * BLOCK_ROWS, LANES)
-    spec = pl.BlockSpec((1, BLOCK_ROWS, LANES), lambda i, j: (i, j, 0))
-    out = pl.pallas_call(
+    spec = pl.BlockSpec((None, BLOCK_ROWS, LANES), lambda i, j: (i, j, 0))
+    return pl.pallas_call(
         _batched_sq_kernel,
         grid=(m, nb),
         in_specs=[spec],
-        out_specs=pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, 1), jnp.float32),
+        out_specs=_SMEM,
+        out_shape=jax.ShapeDtypeStruct((m,), jnp.float32),
+        compiler_params=_sequential(2),
         interpret=interpret,
     )(a.reshape(shape3d))
-    return out[:, 0]
 
 
 def _diff_sq_kernel(a_ref, b_ref, out_ref):
@@ -194,8 +210,9 @@ def diff_sq_norm_flat(a, b, *, interpret=False):
         _diff_sq_kernel,
         grid=(nb,),
         in_specs=[spec, spec],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
+        out_specs=_SMEM,
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
+        compiler_params=_sequential(1),
         interpret=interpret,
     )(a.reshape(shape2d), b.reshape(shape2d))
     return out[0, 0]

@@ -18,7 +18,7 @@ leave zero moments and a zero update, so reductions are unaffected).
 
 Sharded flat planes (the ``shard`` flag on the flat ops, a static
 ``distributed.sharding.FlatSharding``): the same kernels run SHARD-LOCAL
-under a shard_map that is manual over the state-shard axes — each device
+under a shard_map that is manual over every mesh axis — each device
 streams only its ``n_flat / shards`` slice (or its rows of the (M, n_flat)
 planes) — and the scalar reductions (‖Δθ‖², the (M,) rule-LHS norms) are
 completed with ONE psum of fp32 partials. The only cross-device bytes the
@@ -67,11 +67,12 @@ def _pad_plane(a, block=_cu.BLOCK):
     return jnp.pad(a, ((0, 0), (0, pad))) if pad else a
 
 
-def _shard_map(f, shard, in_specs, out_specs, manual):
-    """shard_map manual over ``manual``, auto elsewhere (compat shim)."""
-    from repro.launch.mesh import partial_auto_shard_map
-    return partial_auto_shard_map(f, shard.mesh, in_specs, out_specs,
-                                  manual)
+def _shard_map(f, shard, in_specs, out_specs):
+    """shard_map MANUAL over every axis of the plane's mesh: a Mosaic kernel
+    cannot be partitioned automatically, so no axis may stay auto around
+    it. Axes a spec leaves out are replicated, as the planes are."""
+    return jax.shard_map(f, mesh=shard.mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ------------------------------------------------------------------ flat ops
@@ -85,7 +86,7 @@ def fused_amsgrad_flat(theta, h, vhat, grad, lr, *, b1=0.9, b2=0.999,
     storage dtype (fp32 or bf16 — see kernels/cada_update.py).
 
     ``shard`` (static FlatSharding, optional): run SHARD-LOCAL — manual
-    shard_map over the state-shard axes, each device fusing its own
+    shard_map, each device fusing its own
     ``n_flat / shards`` slice in one pass, with a single psum of the fp32
     ‖Δθ‖² partials. The global result is identical (the padding discipline
     makes every local slice self-contained).
@@ -101,7 +102,7 @@ def fused_amsgrad_flat(theta, h, vhat, grad, lr, *, b1=0.9, b2=0.999,
             return t2, h2, vh2, jax.lax.psum(sq, shard.axes)
 
         return _shard_map(local, shard, (spec,) * 4 + (P(),),
-                          (spec, spec, spec, P()), shard.axes)(
+                          (spec, spec, spec, P()))(
             theta, h, vhat, grad, jnp.asarray(lr, jnp.float32))
     pallas, interpret = _use_pallas(interpret)
     if not pallas:
@@ -172,10 +173,10 @@ def batched_diff_sq_norm(a, b, *, interpret=None, shard=None):
     (``flat.grouped_second_plane``) — all land here as a dense (M, n)
     operand, so the LHS needs no re-gather and no grouping awareness.
 
-    ``shard`` (static FlatSharding, optional): shard-local form — manual
-    over the worker axis (each device sweeps only its own rows) and the
-    plane's column axes, finishing the per-row partials with one psum over
-    the column axes. Rows stay whole per device otherwise.
+    ``shard`` (static FlatSharding, optional): shard-local form — each
+    device sweeps only its own rows (the worker axis) and its slice of the
+    plane's columns, finishing the per-row partials with one psum over the
+    column axes. Rows stay whole per device otherwise.
     """
     if shard is not None:
         from jax.sharding import PartitionSpec as P
@@ -187,7 +188,7 @@ def batched_diff_sq_norm(a, b, *, interpret=None, shard=None):
             return jax.lax.psum(r, cols) if cols else r
 
         return _shard_map(local, shard, (in_spec, in_spec),
-                          P(shard.waxis), shard.plane_axes)(a, b)
+                          P(shard.waxis))(a, b)
     pallas, interpret = _use_pallas(interpret)
     if not pallas:
         d = a.astype(jnp.float32) - b.astype(jnp.float32)
@@ -209,7 +210,7 @@ def batched_sq_norm(a, *, interpret=None, shard=None):
             return jax.lax.psum(r, cols) if cols else r
 
         return _shard_map(local, shard, (shard.worker_spec(),),
-                          P(shard.waxis), shard.plane_axes)(a)
+                          P(shard.waxis))(a)
     pallas, interpret = _use_pallas(interpret)
     if not pallas:
         v = a.astype(jnp.float32)

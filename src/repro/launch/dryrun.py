@@ -34,7 +34,6 @@ from repro.distributed.trainer import (                        # noqa: E402
 )
 from repro.launch.mesh import (                                # noqa: E402
     DCN_BW, HBM_BW, ICI_BW, PEAK_FLOPS_BF16, make_production_mesh,
-    set_mesh,
 )
 from repro.launch.policy import train_policy                   # noqa: E402
 from repro.models.config import active_param_count, param_count  # noqa: E402
@@ -61,7 +60,7 @@ def lower_combo(arch: str, shape_name: str, *, multi_pod: bool = False,
         # mesh's state-shard count
         state_sds = abstract_train_state(
             cfg, hp, m, shards=flat_state_shards(cfg, mesh, hp))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = make(batch_sds).lower(state_sds, batch_sds)
         meta = {"step": "train_step", "rule": hp.rule.kind,
                 "microbatches": hp.microbatches,
@@ -69,13 +68,13 @@ def lower_combo(arch: str, shape_name: str, *, multi_pod: bool = False,
                 "moments_dtype": hp.moments_dtype}
     elif shape.kind == "prefill":
         specs = input_specs(cfg, shape)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             jitted = jit_prefill_step(cfg, mesh, specs)
             lowered = jitted.lower(aps, specs)
         meta = {"step": "prefill"}
     else:  # decode
         specs = input_specs(cfg, shape)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             jitted, cache_sds, inputs_sds = jit_decode_step(
                 cfg, mesh, shape.batch, shape.seq)
             lowered = jitted.lower(aps, cache_sds, inputs_sds)

@@ -1,29 +1,18 @@
-"""Production meshes (TPU v5e targets) + jax version-compat shims.
+"""Production meshes (TPU v5e targets).
 
 single pod:  (16, 16)    axes ("data", "model")        — 256 chips
 multi-pod:   (2, 16, 16) axes ("pod", "data", "model") — 512 chips
 
 Functions, not module constants: importing this module never touches jax
 device state (the dry-run launcher must set XLA_FLAGS before first init).
-
-Version compat: the pinned jax (0.4.x) predates ``jax.sharding.AxisType``
-(and the ``axis_types=`` kwarg of ``jax.make_mesh``), ``jax.set_mesh``, and
-``jax.shard_map``. The helpers below feature-detect once and fall back:
-
-  * :func:`compat_make_mesh` — drops ``axis_types`` when unavailable (all
-    axes are Auto by default there anyway);
-  * :func:`set_mesh` — falls back to the ``Mesh`` context manager;
-  * :func:`partial_auto_shard_map` — maps onto
-    ``jax.experimental.shard_map`` with ``auto=``/``check_rep=``.
+Every mesh is all-Auto: GSPMD propagates from the pinned specs, and code
+that must be manual (the shard-local kernels, the pod-manual gradients)
+says so with ``jax.shard_map``.
 """
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5-era explicit-sharding API
-    from jax.sharding import AxisType as _AxisType
-except ImportError:  # pinned 0.4.x: meshes are implicitly all-Auto
-    _AxisType = None
+from jax.sharding import AxisType
 
 DATA, MODEL, POD = "data", "model", "pod"
 
@@ -34,41 +23,10 @@ ICI_BW = 50e9                 # B/s per link (intra-pod)
 DCN_BW = 6.25e9               # B/s per host pair (inter-pod, ~50 Gbit)
 
 
-def compat_make_mesh(shape, axes):
-    """``jax.make_mesh`` with every axis Auto, on any supported jax."""
-    if _AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(_AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis Auto."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
-
-def set_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    ``jax.set_mesh`` where it exists; the classic ``with mesh:`` context
-    (which jit/with_sharding_constraint consult) on 0.4.x.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh  # jax.sharding.Mesh is itself a context manager
-
-
-def partial_auto_shard_map(f, mesh, in_specs, out_specs, manual_axes):
-    """shard_map that is MANUAL over ``manual_axes`` and auto elsewhere.
-
-    New jax spells this ``jax.shard_map(..., axis_names=..., check_vma=
-    False)``; 0.4.x spells it ``jax.experimental.shard_map.shard_map(...,
-    auto=<complement>, check_rep=False)``.
-    """
-    manual = frozenset(manual_axes)
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=set(manual),
-                             check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False,
-                     auto=frozenset(mesh.axis_names) - manual)
 
 
 def make_production_mesh(*, multi_pod: bool = False, model_par: int = 16):
@@ -80,14 +38,14 @@ def make_production_mesh(*, multi_pod: bool = False, model_par: int = 16):
     data = per_pod // model_par
     shape = (2, data, model_par) if multi_pod else (data, model_par)
     axes = (POD, DATA, MODEL) if multi_pod else (DATA, MODEL)
-    return compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
     """Whatever devices exist locally (tests / CPU smoke runs)."""
     n = len(jax.devices())
     assert n % model == 0
-    return compat_make_mesh((n // model, model), (DATA, MODEL))
+    return make_mesh((n // model, model), (DATA, MODEL))
 
 
 def mesh_axis_size(mesh, name: str) -> int:

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 
 import repro.configs as C
 from repro.distributed.serving import jit_decode_step, jit_prefill_step
-from repro.launch.mesh import make_host_mesh, set_mesh
+from repro.launch.mesh import make_host_mesh
 from repro.models.model import init_params
 
 
@@ -35,7 +35,7 @@ def main() -> None:
     b, s = args.batch, args.prompt_len
     max_seq = s + args.tokens
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = init_params(cfg, jax.random.PRNGKey(0))
         if cfg.embed_input:
             inputs = {"tokens": jax.random.randint(

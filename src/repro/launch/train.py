@@ -8,9 +8,11 @@ every assigned architecture lowers against those).
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import os
 import time
+from typing import Any, NamedTuple
 
 import jax
 import numpy as np
@@ -22,8 +24,18 @@ from repro.core.rules import CommRule
 from repro.data.synthetic import lm_tokens
 from repro.distributed.trainer import (TrainHParams, flat_state_shards,
                                        init_train_state, jit_train_step,
+                                       make_train_step, place_train_state,
                                        worker_split)
-from repro.launch.mesh import make_host_mesh, set_mesh
+from repro.launch.cache import init_compile_cache
+from repro.launch.mesh import make_host_mesh
+
+
+class MeshRun(NamedTuple):
+    """What :func:`run_mesh` leaves behind."""
+    state: Any       # final DistTrainState
+    history: list    # one row per logged step (scalars + upload_mask)
+    step: Any        # the jitted step
+    batch: dict      # its first batch (lower ``step`` on it to inspect)
 
 
 def make_token_batches(cfg, *, global_batch, seq, steps, seed=0):
@@ -137,7 +149,7 @@ def _round_local_steps(rule: CommRule, args) -> int:
     return h
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--arch", required=True, choices=C.list_archs())
     p.add_argument("--smoke", action="store_true",
@@ -250,19 +262,12 @@ def main() -> None:
     p.add_argument("--metrics-prom", default="",
                    help="also write the metrics as a Prometheus "
                         "textfile-collector snapshot to this path")
-    args = p.parse_args()
+    return p
 
-    cfg = (C.get_smoke_config(args.arch) if args.smoke
-           else C.get_config(args.arch))
-    if not cfg.embed_input:
-        raise SystemExit(f"{args.arch} consumes modality embeddings; use "
-                         "examples/serve_decode.py or the dry-run for it")
-    if args.adapt_local_steps and args.runtime != "sim":
-        raise SystemExit(
-            "--adapt-local-steps needs --runtime sim: the adaptation "
-            "signal is comm vs compute time from the sim's link model — "
-            "the mesh runtime has no clock to adapt from")
-    rule = CommRule(kind=args.rule, c=args.c, d_max=10, max_delay=50,
+
+def rule_from_args(args) -> CommRule:
+    """The communication rule the command line asks for."""
+    return CommRule(kind=args.rule, c=args.c, d_max=10, max_delay=50,
                     quantize_bits=args.quantize_bits,
                     error_feedback=not args.no_error_feedback,
                     topk_frac=args.topk_frac,
@@ -276,28 +281,38 @@ def main() -> None:
                     local_steps_max=args.local_steps_max,
                     local_lr=args.local_lr,
                     server_lr=args.lr)
-    if args.runtime == "sim":
-        run_sim(cfg, rule, args)
-        return
-    mesh = make_host_mesh()
-    hp = TrainHParams(rule=rule,
-                      lr=args.lr, microbatches=args.microbatches,
-                      moments_dtype=args.moments_dtype,
-                      state_fsdp_axes=tuple(
-                          a for a in args.state_fsdp_axes.split(",") if a))
-    make, _, m = jit_train_step(cfg, mesh, hp)
-    # the flat layout pads to the mesh's state-shard count: state init
-    # must use the SAME count as the compiled step
-    shards = flat_state_shards(cfg, mesh, hp)
+
+
+def hparams_from_args(rule: CommRule, args) -> TrainHParams:
+    """The trainer hyper-parameters the command line asks for."""
+    return TrainHParams(rule=rule,
+                        lr=args.lr, microbatches=args.microbatches,
+                        moments_dtype=args.moments_dtype,
+                        state_fsdp_axes=tuple(
+                            a for a in args.state_fsdp_axes.split(",") if a))
+
+
+def run_mesh(cfg, rule, args) -> MeshRun:
+    """`--runtime mesh`: train on the host devices — the jitted Algorithm-1
+    step (``jit_train_step`` on the host mesh, or the mesh-free vmapped
+    step over ``--workers`` simulated workers on one device), with
+    checkpointing, per-step logging and the telemetry sinks. The twin of
+    :func:`run_sim`; returns the final state, the logged history and the
+    jitted step with its first batch (for inspecting the compiled
+    program)."""
+    hp = hparams_from_args(rule, args)
     if args.workers:
-        m = args.workers  # host-mesh override (simulated workers)
-        shards = 1        # mesh-free step builder: unsharded flat plane
-        from repro.distributed.trainer import make_train_step
-        # donate the state: the train loop threads it linearly, so the
-        # buffers alias in place instead of being copied every step
+        # simulated workers: the mesh-free step on the default device, over
+        # an unsharded flat plane. The state is donated: the loop threads
+        # it linearly, so the buffers alias in place every step.
+        m, shards, mesh = args.workers, 1, None
         step = jax.jit(make_train_step(cfg, hp, m), donate_argnums=(0,))
     else:
-        step = None
+        mesh = make_host_mesh()
+        make, sspecs, m = jit_train_step(cfg, mesh, hp)
+        # the flat layout pads to the mesh's state-shard count: state init
+        # must use the SAME count as the compiled step
+        shards = flat_state_shards(cfg, mesh, hp)
 
     batches = make_token_batches(cfg, global_batch=args.global_batch,
                                  seq=args.seq, steps=args.steps)
@@ -331,18 +346,19 @@ def main() -> None:
                 ledger.observe_round(met)
             obs_buf.clear()
 
-    with set_mesh(mesh):
+    first = worker_split({"tokens": batches[0]}, m, local_steps=h)
+    with jax.set_mesh(mesh) if mesh else contextlib.nullcontext():
         state = init_train_state(cfg, hp, m, jax.random.PRNGKey(0),
                                  shards=shards)
-        if step is None:
-            sds = jax.tree.map(
-                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-                worker_split({"tokens": batches[0]}, m, local_steps=h))
-            step = make(sds)
+        if mesh is not None:
+            state = place_train_state(state, mesh, sspecs)
+            step = make(jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), first))
 
         t0 = time.time()
         history = []
         for i in range(args.steps):
+            t_step = time.time()
             batch = worker_split({"tokens": batches[i]}, m, local_steps=h)
             with tr.span("train_step", track="train", args={"step": i}):
                 state, mets = step(state, batch)
@@ -351,11 +367,14 @@ def main() -> None:
                 if len(obs_buf) >= max(1, args.metrics_every):
                     drain_obs()
             if i % args.log_every == 0 or i == args.steps - 1:
-                # scalars only: per-worker arrays (upload_mask, staleness)
-                # don't belong in the scalar history log
+                # the scalars, plus the per-worker upload mask as a list
                 row = {k: float(v) for k, v in mets.items()
                        if np.ndim(v) == 0}
+                row["upload_mask"] = np.asarray(mets["upload_mask"]).tolist()
                 row["step"] = i
+                # fetching the scalars waited for the step: step_s is its
+                # host-clock time, the first step's compile included
+                row["step_s"] = time.time() - t_step
                 row["wall_s"] = round(time.time() - t0, 1)
                 history.append(row)
                 print(f"step {i:5d} loss={row['loss']:.4f} "
@@ -380,6 +399,27 @@ def main() -> None:
                "steps": args.steps, "final_loss": float(final),
                **ledger.summary()}
         _write_obs(args, tracer, row)
+    return MeshRun(state, history, step, first)
+
+
+def main() -> None:
+    args = build_parser().parse_args()
+    cfg = (C.get_smoke_config(args.arch) if args.smoke
+           else C.get_config(args.arch))
+    if not cfg.embed_input:
+        raise SystemExit(f"{args.arch} consumes modality embeddings; use "
+                         "examples/serve_decode.py or the dry-run for it")
+    if args.adapt_local_steps and args.runtime != "sim":
+        raise SystemExit(
+            "--adapt-local-steps needs --runtime sim: the adaptation "
+            "signal is comm vs compute time from the sim's link model — "
+            "the mesh runtime has no clock to adapt from")
+    init_compile_cache()
+    rule = rule_from_args(args)
+    if args.runtime == "sim":
+        run_sim(cfg, rule, args)
+    else:
+        run_mesh(cfg, rule, args)
 
 
 if __name__ == "__main__":

@@ -63,6 +63,11 @@ class ModelConfig:
     dtype: str = "bfloat16"
     remat: bool = True        # activation checkpointing over blocks
     source: str = ""          # paper / model-card citation
+    reduced: tuple = ()       # ((key, published value), ...) for every key
+    #                           a one-chip cut changed from the source
+    deployment: str = ""      # what a cut stands for: how the published
+    #                           model is split over chips, of which this is
+    #                           one chip's share
 
     # ------------------------------------------------------------ derived
     @property

@@ -346,8 +346,8 @@ def test_fused_amsgrad_bf16_moments_matches_per_leaf_reference(rng):
 
 def _mesh_shard(shape, axes, waxis, saxes):
     from repro.distributed.sharding import FlatSharding
-    from repro.launch.mesh import compat_make_mesh
-    mesh = compat_make_mesh(shape, axes)
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh(shape, axes)
     return mesh, FlatSharding(mesh=mesh, waxis=waxis, axes=saxes)
 
 

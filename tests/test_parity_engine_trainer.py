@@ -28,8 +28,9 @@ from repro.core.engine import CADAEngine
 from repro.core.rules import RULES, CommRule
 from repro.distributed.trainer import (TrainHParams, flat_state_shards,
                                        init_train_state, jit_train_step,
-                                       make_train_step, worker_split)
-from repro.launch.mesh import compat_make_mesh, set_mesh
+                                       make_train_step, place_train_state,
+                                       worker_split)
+from repro.launch.mesh import make_mesh
 from repro.models.model import init_params, lm_loss
 from repro.optim.adam import adam
 from repro.optim.fused import FusedAMSGrad
@@ -184,7 +185,7 @@ def test_fused_sharded_matches_reference_all_rules(kind):
     one-ulp gradient differences flip quantization buckets (a full
     quantization step, ~1e-4·scale), while the Algorithm-1 decisions stay
     exact."""
-    mesh = compat_make_mesh((8, 1), ("data", "model"))
+    mesh = make_mesh((8, 1), ("data", "model"))
     m, steps = 8, 4
     rule = CommRule(kind=kind, c=20.0, d_max=4, max_delay=10)
     batches = [worker_split(
@@ -193,14 +194,16 @@ def test_fused_sharded_matches_reference_all_rules(kind):
         for i in range(steps)]
 
     hp_s = TrainHParams(rule=rule, lr=LR, state_fsdp_axes=("data",))
-    make, _, mm = jit_train_step(CFG, mesh, hp_s)
+    make, sspecs, mm = jit_train_step(CFG, mesh, hp_s)
     assert mm == m
     sds = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
                        batches[0])
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step = make(sds)
-        st = init_train_state(CFG, hp_s, m, jax.random.PRNGKey(0),
-                              shards=flat_state_shards(CFG, mesh, hp_s))
+        st = place_train_state(
+            init_train_state(CFG, hp_s, m, jax.random.PRNGKey(0),
+                             shards=flat_state_shards(CFG, mesh, hp_s)),
+            mesh, sspecs)
         ms = []
         for b in batches:
             st, met = step(st, b)
@@ -231,11 +234,11 @@ def test_fused_sharded_matches_reference_all_rules(kind):
 def test_sharded_parity_mask_is_mixed():
     """Meta-check for the sharded gate: the cada2 run above exercises both
     uploads and skips."""
-    mesh = compat_make_mesh((8, 1), ("data", "model"))
+    mesh = make_mesh((8, 1), ("data", "model"))
     m, steps = 8, 4
     rule = CommRule(kind="cada2", c=20.0, d_max=4, max_delay=10)
     hp = TrainHParams(rule=rule, lr=LR, state_fsdp_axes=("data",))
-    make, _, _ = jit_train_step(CFG, mesh, hp)
+    make, sspecs, _ = jit_train_step(CFG, mesh, hp)
     batches = [worker_split(
         {"tokens": jax.random.randint(jax.random.PRNGKey(100 + i),
                                       (8, 33), 0, CFG.vocab)}, m)
@@ -243,10 +246,12 @@ def test_sharded_parity_mask_is_mixed():
     sds = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
                        batches[0])
     total = 0
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step = make(sds)
-        st = init_train_state(CFG, hp, m, jax.random.PRNGKey(0),
-                              shards=flat_state_shards(CFG, mesh, hp))
+        st = place_train_state(
+            init_train_state(CFG, hp, m, jax.random.PRNGKey(0),
+                             shards=flat_state_shards(CFG, mesh, hp)),
+            mesh, sspecs)
         for b in batches:
             st, met = step(st, b)
             total += int(met["uploads"])

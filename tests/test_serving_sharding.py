@@ -13,8 +13,7 @@ import repro.configs as C
 from repro.distributed.serving import jit_decode_step, jit_prefill_step
 from repro.distributed.sharding import (batch_pspecs, cache_pspecs,
                                         param_pspecs, wants_fsdp)
-from repro.launch.mesh import (make_host_mesh, make_production_mesh,
-                               set_mesh)
+from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models.model import abstract_params, init_cache, init_params
 
 
@@ -67,7 +66,7 @@ def test_cache_pspecs_shapes():
 def test_decode_step_builder_runs(arch):
     cfg = C.get_smoke_config(arch)
     mesh = make_host_mesh()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = init_params(cfg, jax.random.PRNGKey(0))
         step, cache_sds, inputs_sds = jit_decode_step(cfg, mesh, 2, 16)
         cache = init_cache(cfg, 2, 16)
@@ -80,7 +79,7 @@ def test_decode_step_builder_runs(arch):
 def test_prefill_step_builder_runs():
     cfg = C.get_smoke_config("granite-moe-1b-a400m")
     mesh = make_host_mesh()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = init_params(cfg, jax.random.PRNGKey(0))
         inputs = {"tokens": jnp.ones((2, 16), jnp.int32)}
         sds = jax.tree.map(

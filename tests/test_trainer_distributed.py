@@ -11,10 +11,11 @@ import repro.configs as C
 from repro.core.local_update import LocalUpdateEngine
 from repro.core.rules import CommRule
 from repro.distributed.trainer import (
-    DistTrainState, TrainHParams, init_train_state, jit_train_step,
-    make_train_step, train_state_specs, worker_split, worker_split_abstract,
+    DistTrainState, TrainHParams, flat_state_shards, init_train_state,
+    jit_train_step, make_train_step, place_train_state, train_state_specs,
+    worker_split, worker_split_abstract,
 )
-from repro.launch.mesh import make_host_mesh, set_mesh
+from repro.launch.mesh import make_host_mesh
 
 CFG = C.get_smoke_config("internlm2-1.8b")
 
@@ -121,7 +122,7 @@ def test_state_specs_structure():
 @pytest.mark.skipif(len(jax.devices()) < 8,
                     reason="needs XLA_FLAGS=--xla_force_host_platform_"
                            "device_count=8 (the CI mesh matrix leg)")
-def test_flat_round_with_manual_shard_maps_on_pod_mesh(monkeypatch):
+def test_flat_round_with_manual_shard_maps_on_pod_mesh():
     """The flat state plane on the MULTI-POD mesh (worker = pod): a
     (pod=2, data=4, model=1) mesh with the CADA state sharded over 'data'
     — worker planes shard pod × data, so the batched LHS and the fused
@@ -129,16 +130,13 @@ def test_flat_round_with_manual_shard_maps_on_pod_mesh(monkeypatch):
     fp32 partials over the column shards. The run must match the
     mesh-free reference's masks.
 
-    The pod-manual VGRAD shard_map stays off (REPRO_NO_PODMAP): executing
-    it trips an XLA spmd-partitioner CHECK (hlo_sharding_util.cc
-    IsManualSubgroup) on the pinned jax 0.4.37 for BOTH state planes —
-    a pre-existing partial-auto limitation recorded in ROADMAP's
-    jax-compat item (revisit at jax >= 0.6). The kernel-side manual
-    shard_maps this test exercises are the flat round's own."""
-    from repro.launch.mesh import compat_make_mesh
-    from repro.distributed.trainer import flat_state_shards
-    monkeypatch.setenv("REPRO_NO_PODMAP", "1")
-    mesh = compat_make_mesh((2, 4, 1), ("pod", "data", "model"))
+    The per-worker gradients run under the pod-manual vgrad shard_map
+    (``make_pod_vgrads``), nested around the flat round's own manual
+    shard_maps; the server plane is pinned straight from the packed
+    buffer to its data-sharded layout (``FlatSharding.constrain_server``),
+    and ``unpack`` reading it back in order is what the masks check."""
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4, 1), ("pod", "data", "model"))
     hp = TrainHParams(rule=CommRule(kind="cada2", c=20.0, d_max=4,
                                     max_delay=10), lr=1e-3,
                       shard_cada_state=True)
@@ -149,10 +147,12 @@ def test_flat_round_with_manual_shard_maps_on_pod_mesh(monkeypatch):
     sds = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
                        batches[0])
     mets = []
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step = make(sds)
-        st = init_train_state(CFG, hp, m, jax.random.PRNGKey(42),
-                              shards=flat_state_shards(CFG, mesh, hp))
+        st = place_train_state(
+            init_train_state(CFG, hp, m, jax.random.PRNGKey(42),
+                             shards=flat_state_shards(CFG, mesh, hp)),
+            mesh, sspecs)
         for b in batches:
             st, mm = step(st, b)
             mets.append(mm)
@@ -175,13 +175,16 @@ def test_jit_train_step_on_host_mesh():
     mesh = make_host_mesh()
     hp = TrainHParams(rule=CommRule(kind="cada2", c=0.5, d_max=4,
                                     max_delay=10), microbatches=2)
-    make, _, m = jit_train_step(CFG, mesh, hp)
+    make, sspecs, m = jit_train_step(CFG, mesh, hp)
     batch = worker_split(_batch(jax.random.PRNGKey(0)), m)
     sds = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
                        batch)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step = make(sds)
-        st = init_train_state(CFG, hp, m, jax.random.PRNGKey(0))
+        st = place_train_state(
+            init_train_state(CFG, hp, m, jax.random.PRNGKey(0),
+                             shards=flat_state_shards(CFG, mesh, hp)),
+            mesh, sspecs)
         st, mets = step(st, batch)
     assert np.isfinite(float(mets["loss"]))
 
