@@ -101,10 +101,6 @@ def bench_cada(iters: int = 300, lm_steps: int = 30) -> dict:
         comm state + the eval-point extras — the ring-vs-dense story);
       * an interleaved M-sweep micro-arm (M=10/256/2048) showing the
         ring's memory and steps/sec scaling (``m_sweep``);
-      * an ``obs_overhead`` arm (interleaved best-of-N per-step loops)
-        asserting the telemetry plane's contract: the disabled path
-        (NULL tracer + unfed ledger) costs <2% steps/sec and the enabled
-        path (real spans + every-8 metric fetch into a ledger) <10%;
       * trainer steps/sec on the LM path (ROADMAP's named next metric).
 
     Warns on stderr when any steps/sec regresses >10% vs the committed
@@ -191,7 +187,6 @@ def bench_cada(iters: int = 300, lm_steps: int = 30) -> dict:
         1.0 - out["cada2_unfused"]["steps_per_sec"]
         / out["always"]["steps_per_sec"], 4)
     out["m_sweep"] = _bench_m_sweep()
-    out["obs_overhead"] = _bench_obs_overhead()
 
     lm = bench_trainer_lm(lm_steps)
     out.update(lm)
@@ -210,123 +205,6 @@ def bench_cada(iters: int = 300, lm_steps: int = 30) -> dict:
           f"fallback {out['sharded_perleaf_ref']['steps_per_sec']}) "
           f"-> {BENCH_PATH}", file=sys.stderr)
     return out
-
-
-def _bench_obs_overhead(iters: int = 200, reps: int = 4) -> dict:
-    """The telemetry plane's overhead contract, measured on the per-step
-    host loop (the only place obs code runs — the scanned ``eng.run``
-    path has no per-round host hook to instrument). The workload is the
-    MNIST-like MLP at trainer scale (several ms/step): that is the loop
-    ``launch/train.py --trace/--metrics-out`` instruments, and the obs
-    costs are fixed per step (a ~1µs span, one 11-leaf metric fetch per
-    ``metrics_every`` window), so a sub-ms microbenchmark step would
-    measure jax dispatch overhead rather than the telemetry plane.
-
-    Three arms over the same jitted single-step engine call, compiled
-    first then interleaved chunk by chunk, best-of-many chunks:
-
-      * ``baseline`` — bare loop, no obs code at all;
-      * ``disabled`` — the instrumented loop with tracing off: a
-        ``NULL`` tracer span per step plus the ledger-feed branch not
-        taken. This is the path every untraced run pays;
-      * ``enabled`` — a real :class:`~repro.obs.trace.Tracer` span per
-        step and round metrics buffered on device, fetched every 8 steps
-        into a :class:`~repro.obs.metrics.CommLedger`.
-
-    Asserts ``obs_overhead_frac_disabled < 0.02`` and
-    ``obs_overhead_frac_enabled < 0.10`` (fractions clamp at 0 — arms
-    faster than baseline are machine noise, not negative overhead).
-    """
-    import jax
-
-    from repro.core.engine import CADAEngine, make_sampler
-    from repro.core.rules import CommRule
-    from repro.data.partition import pad_to_matrix, uniform_partition
-    from repro.data.synthetic import mnist_like
-    from repro.models.small import mlp_init, mlp_loss
-    from repro.obs import NULL, CommLedger, Tracer
-    from repro.optim.fused import FusedAMSGrad
-
-    m = 10
-    ds = mnist_like(n=2048)
-    mtx = pad_to_matrix(uniform_partition(ds.n, m, seed=0))
-    sample = make_sampler(ds.x.reshape(len(ds.x), -1), ds.y, mtx, 32)
-    eng = CADAEngine(mlp_loss, FusedAMSGrad(lr=0.01),
-                     CommRule(kind="cada2", c=0.6, d_max=10,
-                              max_delay=100), m)
-    st0 = eng.init(mlp_init(jax.random.PRNGKey(0), 784, 64, 10))
-    batches = jax.vmap(sample)(
-        jax.random.split(jax.random.PRNGKey(2), iters))
-    step = jax.jit(eng.step, donate_argnums=(0,))
-
-    def make_plain():
-        st = [jax.tree.map(lambda x: x.copy(), st0)]
-
-        def go(lo: int, hi: int) -> None:
-            s = st[0]
-            for i in range(lo, hi):
-                s, _ = step(s, jax.tree.map(lambda x: x[i], batches))
-            jax.block_until_ready(s.params)
-            st[0] = s
-        return go
-
-    def make_obs(tracer, ledger):
-        st = [jax.tree.map(lambda x: x.copy(), st0)]
-        buf: list = []
-
-        def go(lo: int, hi: int) -> None:
-            s = st[0]
-            for i in range(lo, hi):
-                b = jax.tree.map(lambda x: x[i], batches)
-                with tracer.span("train_step", track="train",
-                                 args={"step": i}):
-                    s, met = step(s, b)
-                if ledger is not None:
-                    buf.append(met)
-                    if len(buf) >= 8:
-                        for row in jax.device_get(buf):
-                            ledger.observe_round(row)
-                        buf.clear()
-            jax.block_until_ready(s.params)
-            st[0] = s
-        return go
-
-    arms = {
-        "baseline": make_plain(),
-        "disabled": make_obs(NULL, None),
-        "enabled": make_obs(Tracer(),
-                            CommLedger.for_strategy(eng.strategy)),
-    }
-    # Each sample times one CHUNK of steps, arms alternating chunk by
-    # chunk; best-of-many chunks per arm. Fine-grained interleaving is
-    # what makes a <2% assertion tenable on a noisy shared box — timing
-    # whole loops back to back folds multi-percent machine drift into
-    # the ratio (observed: spurious 2-4% on identical code paths).
-    chunk = 25
-    for go in arms.values():             # compile + steady-state warmup
-        go(0, chunk)
-    best = {k: float("inf") for k in arms}
-    windows = [(lo, lo + chunk)
-               for lo in range(chunk, iters - chunk + 1, chunk)]
-    for _ in range(reps):
-        for lo, hi in windows:
-            for name, go in arms.items():
-                t0 = time.time()
-                go(lo, hi)
-                best[name] = min(best[name], time.time() - t0)
-    sps = {k: chunk / v for k, v in best.items()}
-    dis = max(0.0, 1.0 - sps["disabled"] / sps["baseline"])
-    ena = max(0.0, 1.0 - sps["enabled"] / sps["baseline"])
-    assert dis < 0.02, (
-        f"disabled obs path costs {dis:.1%} steps/sec (contract: <2%)")
-    assert ena < 0.10, (
-        f"enabled obs path costs {ena:.1%} steps/sec (contract: <10%)")
-    return {
-        "iters": iters,
-        "steps_per_sec": {k: round(v, 1) for k, v in sps.items()},
-        "obs_overhead_frac_disabled": round(dis, 4),
-        "obs_overhead_frac_enabled": round(ena, 4),
-    }
 
 
 def _bench_m_sweep(ms=(10, 256, 2048), iters=(300, 100, 15),

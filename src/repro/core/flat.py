@@ -102,41 +102,46 @@ class FlatLayout:
         """(..., shards, shard_len) per-shard view -> (..., n_flat)."""
         return parts.reshape(parts.shape[:-2] + (self.n_flat,))
 
-    # ---- conversions
+    # ---- conversions: every move between pytrees and flat planes is named
+    # ``cada.pack`` on the device, wherever it is called from
     def pack(self, tree, dtype=jnp.float32) -> jnp.ndarray:
         """Pytree -> (n_flat,) buffer in ``dtype`` (zero-padded tail)."""
-        leaves = jax.tree.leaves(tree)
-        flat = jnp.concatenate(
-            [jnp.ravel(l).astype(dtype) for l in leaves])
-        if self.n_flat > self.n:
-            flat = jnp.pad(flat, (0, self.n_flat - self.n))
-        return flat
+        with jax.named_scope("cada.pack"):
+            leaves = jax.tree.leaves(tree)
+            flat = jnp.concatenate(
+                [jnp.ravel(l).astype(dtype) for l in leaves])
+            if self.n_flat > self.n:
+                flat = jnp.pad(flat, (0, self.n_flat - self.n))
+            return flat
 
     def pack_worker(self, tree, dtype=jnp.float32) -> jnp.ndarray:
         """M-leading pytree -> (M, n_flat) plane in ``dtype``."""
-        leaves = jax.tree.leaves(tree)
-        m = leaves[0].shape[0]
-        flat = jnp.concatenate(
-            [l.reshape(m, -1).astype(dtype) for l in leaves], axis=1)
-        if self.n_flat > self.n:
-            flat = jnp.pad(flat, ((0, 0), (0, self.n_flat - self.n)))
-        return flat
+        with jax.named_scope("cada.pack"):
+            leaves = jax.tree.leaves(tree)
+            m = leaves[0].shape[0]
+            flat = jnp.concatenate(
+                [l.reshape(m, -1).astype(dtype) for l in leaves], axis=1)
+            if self.n_flat > self.n:
+                flat = jnp.pad(flat, ((0, 0), (0, self.n_flat - self.n)))
+            return flat
 
     def unpack(self, buf, dtypes=None):
         """(n_flat,) buffer -> pytree (leaves cast to the layout dtypes)."""
         dtypes = dtypes or self.dtypes
-        outs = [buf[o:o + s].reshape(shp).astype(dt)
-                for o, s, shp, dt in zip(self.offsets, self.sizes,
-                                         self.shapes, dtypes)]
+        with jax.named_scope("cada.pack"):
+            outs = [buf[o:o + s].reshape(shp).astype(dt)
+                    for o, s, shp, dt in zip(self.offsets, self.sizes,
+                                             self.shapes, dtypes)]
         return jax.tree.unflatten(self.treedef, outs)
 
     def unpack_worker(self, buf, dtypes=None):
         """(M, n_flat) plane -> M-leading pytree."""
         dtypes = dtypes or self.dtypes
         m = buf.shape[0]
-        outs = [buf[:, o:o + s].reshape((m,) + shp).astype(dt)
-                for o, s, shp, dt in zip(self.offsets, self.sizes,
-                                         self.shapes, dtypes)]
+        with jax.named_scope("cada.pack"):
+            outs = [buf[:, o:o + s].reshape((m,) + shp).astype(dt)
+                    for o, s, shp, dt in zip(self.offsets, self.sizes,
+                                             self.shapes, dtypes)]
         return jax.tree.unflatten(self.treedef, outs)
 
     # ---- dtype discipline
@@ -150,11 +155,13 @@ class FlatLayout:
         reduced-precision leaves. No-op for all-fp32 layouts (static)."""
         if self.all_f32:
             return buf
-        parts = [buf[o:o + s].astype(dt).astype(buf.dtype)
-                 for o, s, dt in zip(self.offsets, self.sizes, self.dtypes)]
-        if self.n_flat > self.n:
-            parts.append(buf[self.n:])
-        return jnp.concatenate(parts)
+        with jax.named_scope("cada.pack"):
+            parts = [buf[o:o + s].astype(dt).astype(buf.dtype)
+                     for o, s, dt in zip(self.offsets, self.sizes,
+                                         self.dtypes)]
+            if self.n_flat > self.n:
+                parts.append(buf[self.n:])
+            return jnp.concatenate(parts)
 
 
 def layout_of(tree, align: int | None = None, shards: int = 1) -> FlatLayout:
@@ -450,7 +457,8 @@ def grouped_second_plane(layout: FlatLayout, ring, slot, batch, m: int,
 
     def body(acc, r):
         def eval_row(a):
-            row = jax.tree.map(lambda x: x[r], ring)
+            with jax.named_scope("cada.rule_state"):
+                row = jax.tree.map(lambda x: x[r], ring)
             _, g = vgrad(row, batch)
             return jnp.where((slot == r)[:, None], layout.pack_worker(g), a)
 
@@ -496,48 +504,55 @@ def eval_two_point(strategy, layout: FlatLayout, extras: dict, params,
 
     The legacy dense ``second_eval_per_worker`` hook is honored last, for
     external strategies without a ring.
+
+    On the device the evaluations are named ``cada.grad_eval``, the ring
+    gather ``cada.rule_state`` and the packing ``cada.pack``. The fresh and
+    second evaluations share one name: on the stacked route they are rows
+    of one vmapped call, which no name can divide.
     """
-    indexed = strategy.second_eval_indexed(extras)
-    if indexed is not None:
-        ring, slot = indexed
-        if cohort is not None and slot is not None:
-            slot = slot[cohort]
-        if slot is None:  # degenerate ring: one shared point
-            shared_pt = jax.tree.map(lambda x: jnp.squeeze(x, 0), ring)
+    with jax.named_scope("cada.grad_eval"):
+        indexed = strategy.second_eval_indexed(extras)
+        if indexed is not None:
+            ring, slot = indexed
+            if cohort is not None and slot is not None:
+                slot = slot[cohort]
+            if slot is None:  # degenerate ring: one shared point
+                shared_pt = jax.tree.map(lambda x: jnp.squeeze(x, 0), ring)
+                losses, fresh_tree = vgrad(params, batch)
+                _, second_tree = vgrad(shared_pt, batch)
+                return (losses, layout.pack_worker(fresh_tree),
+                        layout.pack_worker(second_tree))
+            if group_evals:
+                losses, fresh_tree = vgrad(params, batch)
+                return (losses, layout.pack_worker(fresh_tree),
+                        grouped_second_plane(layout, ring, slot, batch, m,
+                                             vgrad))
+            with jax.named_scope("cada.rule_state"):
+                pts = jax.tree.map(lambda x: x[slot], ring)
+            if fuse_evals:
+                return stacked_two_point_eval(layout, params, pts, batch, m,
+                                              vgrad_per)
             losses, fresh_tree = vgrad(params, batch)
-            _, second_tree = vgrad(shared_pt, batch)
+            _, second_tree = vgrad_per(pts, batch)
             return (losses, layout.pack_worker(fresh_tree),
                     layout.pack_worker(second_tree))
-        if group_evals:
-            losses, fresh_tree = vgrad(params, batch)
-            return (losses, layout.pack_worker(fresh_tree),
-                    grouped_second_plane(layout, ring, slot, batch, m,
-                                         vgrad))
-        pts = jax.tree.map(lambda x: x[slot], ring)
-        if fuse_evals:
-            return stacked_two_point_eval(layout, params, pts, batch, m,
+
+        shared_pt = strategy.second_eval_shared(extras)
+        perw_pts = strategy.second_eval_per_worker(extras)
+        if perw_pts is not None and fuse_evals:
+            return stacked_two_point_eval(layout, params, perw_pts, batch, m,
                                           vgrad_per)
         losses, fresh_tree = vgrad(params, batch)
-        _, second_tree = vgrad_per(pts, batch)
-        return (losses, layout.pack_worker(fresh_tree),
-                layout.pack_worker(second_tree))
-
-    shared_pt = strategy.second_eval_shared(extras)
-    perw_pts = strategy.second_eval_per_worker(extras)
-    if perw_pts is not None and fuse_evals:
-        return stacked_two_point_eval(layout, params, perw_pts, batch, m,
-                                      vgrad_per)
-    losses, fresh_tree = vgrad(params, batch)
-    fresh = layout.pack_worker(fresh_tree)
-    if shared_pt is not None:
-        _, second_tree = vgrad(shared_pt, batch)
-        second = layout.pack_worker(second_tree)
-    elif perw_pts is not None:
-        _, second_tree = vgrad_per(perw_pts, batch)
-        second = layout.pack_worker(second_tree)
-    else:
-        second = None
-    return losses, fresh, second
+        fresh = layout.pack_worker(fresh_tree)
+        if shared_pt is not None:
+            _, second_tree = vgrad(shared_pt, batch)
+            second = layout.pack_worker(second_tree)
+        elif perw_pts is not None:
+            _, second_tree = vgrad_per(perw_pts, batch)
+            second = layout.pack_worker(second_tree)
+        else:
+            second = None
+        return losses, fresh, second
 
 
 # ------------------------------------------------------------- shared round
@@ -602,7 +617,8 @@ def flat_comm_round(strategy, layout: FlatLayout, comm: FlatCommState,
             "(local_momentum, fedadam)")
 
     # Line 4 (rule-owned): e.g. CADA1 snapshot refresh every D iterations.
-    extras = strategy.flat_pre_step(comm.extras, params, params_flat, k)
+    with jax.named_scope("cada.rule_state"):
+        extras = strategy.flat_pre_step(comm.extras, params, params_flat, k)
 
     if strategy.delta_payload:
         # Payload/cadence branch: the worker runs h_w local optimizer
@@ -616,9 +632,10 @@ def flat_comm_round(strategy, layout: FlatLayout, comm: FlatCommState,
         batch_h = (batch if batch_has_local_axis(r, local_steps)
                    else jax.tree.map(lambda x: x[None], batch))
         h_steps = local_steps_vector(r, m, batch_h, local_steps)
-        losses, fresh, cache = strategy.flat_local_payload(
-            layout, extras, params, params_flat, batch_h, m, vgrad_per,
-            h_steps)
+        with jax.named_scope("cada.grad_eval"):
+            losses, fresh, cache = strategy.flat_local_payload(
+                layout, extras, params, params_flat, batch_h, m, vgrad_per,
+                h_steps)
         second = None
         ctx = FlatCommContext(layout=layout, params=params,
                               params_flat=params_flat, batch=batch,
@@ -646,49 +663,56 @@ def flat_comm_round(strategy, layout: FlatLayout, comm: FlatCommState,
                               participation=participation)
 
         # Lines 7/9: rule LHS vs the shared recent-progress RHS.
-        lhs, cache = strategy.flat_lhs(ctx, extras)
-    rhs = r.rhs(comm.diff_hist)
-    # Line 10: upload if the condition is VIOLATED or staleness capped.
-    upload = (lhs > rhs) | (comm.staleness >= r.max_delay)
-    if participation is not None:
-        upload = upload & participation
+        with jax.named_scope("cada.gate"):
+            lhs, cache = strategy.flat_lhs(ctx, extras)
+    with jax.named_scope("cada.gate"):
+        rhs = r.rhs(comm.diff_hist)
+        # Line 10: upload if the condition is VIOLATED or staleness capped.
+        upload = (lhs > rhs) | (comm.staleness >= r.max_delay)
+        if participation is not None:
+            upload = upload & participation
 
-    # Eq. (3): innovation delta, wire format, masked aggregation — each a
-    # single whole-plane op (one (M, n_flat) sweep instead of ~6 tree_maps).
-    wg32 = comm.worker_grads.astype(jnp.float32)
-    delta = strategy.flat_wire_delta(ctx, extras, cache, fresh - wg32)
-    sparse = strategy.flat_sparse_wire(ctx, extras, cache, delta)
-    if sparse is not None:
-        # True sparse wire: the (M, K) value/index pair is the collective
-        # payload; the dense plane is reconstructed server-side. Values are
-        # masked and cast exactly like the dense wire, so the two paths
-        # are bit-equal wherever the extraction captured the full support.
-        vals, idx = sparse
-        vals = jnp.where(upload[:, None], vals, 0.0).astype(
-            comm.worker_grads.dtype)
-        wire = sparse_rows_to_dense(idx, vals, layout.n_flat)
-    else:
-        wire = jnp.where(upload[:, None], delta, 0.0).astype(
-            comm.worker_grads.dtype)
-    if shard is not None:
-        # pin the wire to the worker-plane layout: the cross-worker mean
-        # below IS the gated collective, and an unpinned intermediate lets
-        # GSPMD gather the full plane before reducing it.
-        wire = shard.constrain_worker(wire)
-    # Order-fixed row accumulation (kops.eq3_row_mean): masked zero rows
-    # are exact no-ops, so this dense masked mean is BIT-IDENTICAL to the
-    # cohort plane's C-row sum below (flat_cohort_round) — the parity the
-    # cohort tests pin.
-    nabla = (comm.nabla.astype(jnp.float32)
-             + kops.eq3_row_mean(wire, m, shard=shard)
-             ).astype(comm.nabla.dtype)
-    if shard is not None:
-        nabla = shard.constrain_server(nabla)
-    worker_grads = (wg32 + wire.astype(jnp.float32)
-                    ).astype(comm.worker_grads.dtype)
+    with jax.named_scope("cada.eq3"):
+        # Eq. (3): innovation delta, wire format, masked aggregation — each
+        # a single whole-plane op (one (M, n_flat) sweep instead of ~6
+        # tree_maps).
+        wg32 = comm.worker_grads.astype(jnp.float32)
+        delta = strategy.flat_wire_delta(ctx, extras, cache, fresh - wg32)
+        sparse = strategy.flat_sparse_wire(ctx, extras, cache, delta)
+        if sparse is not None:
+            # True sparse wire: the (M, K) value/index pair is the
+            # collective payload; the dense plane is reconstructed
+            # server-side. Values are masked and cast exactly like the dense
+            # wire, so the two paths are bit-equal wherever the extraction
+            # captured the full support.
+            vals, idx = sparse
+            vals = jnp.where(upload[:, None], vals, 0.0).astype(
+                comm.worker_grads.dtype)
+            wire = sparse_rows_to_dense(idx, vals, layout.n_flat)
+        else:
+            wire = jnp.where(upload[:, None], delta, 0.0).astype(
+                comm.worker_grads.dtype)
+        if shard is not None:
+            # pin the wire to the worker-plane layout: the cross-worker
+            # mean below IS the gated collective, and an unpinned
+            # intermediate lets GSPMD gather the full plane before reducing
+            # it.
+            wire = shard.constrain_worker(wire)
+        # Order-fixed row accumulation (kops.eq3_row_mean): masked zero
+        # rows are exact no-ops, so this dense masked mean is BIT-IDENTICAL
+        # to the cohort plane's C-row sum below (flat_cohort_round) — the
+        # parity the cohort tests pin.
+        nabla = (comm.nabla.astype(jnp.float32)
+                 + kops.eq3_row_mean(wire, m, shard=shard)
+                 ).astype(comm.nabla.dtype)
+        if shard is not None:
+            nabla = shard.constrain_server(nabla)
+        worker_grads = (wg32 + wire.astype(jnp.float32)
+                        ).astype(comm.worker_grads.dtype)
 
-    staleness = jnp.where(upload, 1, comm.staleness + 1)
-    extras = strategy.flat_post_upload(extras, cache, upload, ctx)
+        staleness = jnp.where(upload, 1, comm.staleness + 1)
+    with jax.named_scope("cada.rule_state"):
+        extras = strategy.flat_post_upload(extras, cache, upload, ctx)
 
     uploads = jnp.sum(upload.astype(jnp.int32))
     # offline workers evaluate nothing — charge grad evals to participants
@@ -1051,7 +1075,8 @@ def flat_cohort_round(strategy, layout: FlatLayout,
         nabla=server.nabla, worker_grads=rows["worker_grads"],
         staleness=stale_c, diff_hist=server.diff_hist, extras=merged)
 
-    extras = strategy.flat_pre_step(merged, params, params_flat, k)
+    with jax.named_scope("cada.rule_state"):
+        extras = strategy.flat_pre_step(merged, params, params_flat, k)
     if strategy.delta_payload:
         # Payload/cadence branch on the cohort plane: the C sampled
         # workers run their local steps (fixed H — the cohort plane does
@@ -1060,9 +1085,10 @@ def flat_cohort_round(strategy, layout: FlatLayout,
         batch_h = (batch if batch_has_local_axis(r, None)
                    else jax.tree.map(lambda x: x[None], batch))
         h_steps = local_steps_vector(r, c, batch_h, None)
-        losses, fresh, cache = strategy.flat_local_payload(
-            layout, extras, params, params_flat, batch_h, c, vgrad_per,
-            h_steps)
+        with jax.named_scope("cada.grad_eval"):
+            losses, fresh, cache = strategy.flat_local_payload(
+                layout, extras, params, params_flat, batch_h, c, vgrad_per,
+                h_steps)
         second = None
         ctx = FlatCommContext(layout=layout, params=params,
                               params_flat=params_flat, batch=batch,
@@ -1084,32 +1110,36 @@ def flat_cohort_round(strategy, layout: FlatLayout,
                               step=k, m=c, interpret=interpret, shard=None,
                               participation=None, cohort=cohort)
 
-        lhs, cache = strategy.flat_lhs(ctx, extras)
-    rhs = r.rhs(server.diff_hist)
-    upload = (lhs > rhs) | (stale_c >= r.max_delay)
+        with jax.named_scope("cada.gate"):
+            lhs, cache = strategy.flat_lhs(ctx, extras)
+    with jax.named_scope("cada.gate"):
+        rhs = r.rhs(server.diff_hist)
+        upload = (lhs > rhs) | (stale_c >= r.max_delay)
 
-    wg32 = rows["worker_grads"].astype(jnp.float32)
-    delta = strategy.flat_wire_delta(ctx, extras, cache, fresh - wg32)
-    sparse = strategy.flat_sparse_wire(ctx, extras, cache, delta)
-    if sparse is not None:
-        vals, idx = sparse
-        vals = jnp.where(upload[:, None], vals, 0.0).astype(
-            rows["worker_grads"].dtype)
-        wire = sparse_rows_to_dense(idx, vals, layout.n_flat)
-    else:
-        wire = jnp.where(upload[:, None], delta, 0.0).astype(
-            rows["worker_grads"].dtype)
-    # ∇̄ += Σ_cohort δ_m / M — the incremental aggregate; the (M-C)
-    # offline rows would contribute exact zeros, so the dense masked mean
-    # is reproduced bit-for-bit without ever materializing it.
-    nabla = (server.nabla.astype(jnp.float32)
-             + kops.eq3_row_mean(wire, m_total)).astype(server.nabla.dtype)
-    worker_grads = (wg32 + wire.astype(jnp.float32)
-                    ).astype(rows["worker_grads"].dtype)
+    with jax.named_scope("cada.eq3"):
+        wg32 = rows["worker_grads"].astype(jnp.float32)
+        delta = strategy.flat_wire_delta(ctx, extras, cache, fresh - wg32)
+        sparse = strategy.flat_sparse_wire(ctx, extras, cache, delta)
+        if sparse is not None:
+            vals, idx = sparse
+            vals = jnp.where(upload[:, None], vals, 0.0).astype(
+                rows["worker_grads"].dtype)
+            wire = sparse_rows_to_dense(idx, vals, layout.n_flat)
+        else:
+            wire = jnp.where(upload[:, None], delta, 0.0).astype(
+                rows["worker_grads"].dtype)
+        # ∇̄ += Σ_cohort δ_m / M — the incremental aggregate; the (M-C)
+        # offline rows would contribute exact zeros, so the dense masked
+        # mean is reproduced bit-for-bit without ever materializing it.
+        nabla = (server.nabla.astype(jnp.float32)
+                 + kops.eq3_row_mean(wire, m_total)).astype(server.nabla.dtype)
+        worker_grads = (wg32 + wire.astype(jnp.float32)
+                        ).astype(rows["worker_grads"].dtype)
 
-    staleness = (server.staleness + 1).at[cohort].set(
-        jnp.where(upload, 1, stale_c + 1))
-    extras = strategy.flat_post_upload(extras, cache, upload, ctx)
+        staleness = (server.staleness + 1).at[cohort].set(
+            jnp.where(upload, 1, stale_c + 1))
+    with jax.named_scope("cada.rule_state"):
+        extras = strategy.flat_post_upload(extras, cache, upload, ctx)
     new_rows = {"worker_grads": worker_grads,
                 **{name: extras[name] for name in pooled}}
     server_extras = {name: v for name, v in extras.items()

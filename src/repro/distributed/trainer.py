@@ -488,6 +488,14 @@ def make_train_step(cfg: ModelConfig, hp: TrainHParams, m: int,
     slices and the fused kernels + LHS norms run shard-local with psum'd
     scalars. Mesh-free callers leave both at their defaults (unsharded
     plane, plain whole-plane ops).
+
+    On the device every phase of the step carries a ``jax.named_scope``:
+    ``cada.grad_eval`` (the workers' forward and backward passes),
+    ``cada.pack`` (pytree <-> flat plane), ``cada.rule_state`` (the
+    rule's evaluation-point state), ``cada.gate`` (LHS, RHS, upload mask),
+    ``cada.eq3`` (the aggregate) and ``cada.server_update`` (AMSGrad and
+    the RHS history). The innermost name lands in each compiled
+    instruction's ``op_name``, so a profile splits the step by phase.
     """
     strategy = strategy_for(hp.rule)
     if wconstrain is None:
@@ -543,14 +551,17 @@ def make_train_step(cfg: ModelConfig, hp: TrainHParams, m: int,
     # not fit in HBM.
     if strategy.stateless:
         def step_always(state: DistTrainState, batch):
-            losses, fresh = vgrad(state.params, batch)
+            with jax.named_scope("cada.grad_eval"):
+                losses, fresh = vgrad(state.params, batch)
             if use_flat:
-                grad_flat = jnp.mean(layout.pack_worker(fresh), axis=0)
-                if flat_shard is not None:
-                    grad_flat = flat_shard.constrain_server(grad_flat)
-                params, h, vhat, dsq = fused_update(
-                    pack_server(state.params), state.h, state.vhat,
-                    grad_flat)
+                with jax.named_scope("cada.eq3"):
+                    grad_flat = jnp.mean(layout.pack_worker(fresh), axis=0)
+                    if flat_shard is not None:
+                        grad_flat = flat_shard.constrain_server(grad_flat)
+                pflat = pack_server(state.params)
+                with jax.named_scope("cada.server_update"):
+                    params, h, vhat, dsq = fused_update(
+                        pflat, state.h, state.vhat, grad_flat)
             else:
                 grad = jax.tree.map(lambda g: jnp.mean(g, axis=0), fresh)
                 params, h, vhat, dsq = _amsgrad_apply(
@@ -584,9 +595,10 @@ def make_train_step(cfg: ModelConfig, hp: TrainHParams, m: int,
                 strategy, layout, state.comm, state.params, pflat, batch,
                 k, vgrad=vgrad, vgrad_per=vgrad_per, fuse_evals=fuse_evals,
                 group_evals=hp.group_evals, shard=flat_shard)
-            params, h, vhat, dsq = fused_update(
-                pflat, state.h, state.vhat, F.nabla_f32(out.comm))
-            comm = F.record_progress(out.comm, dsq, k)
+            with jax.named_scope("cada.server_update"):
+                params, h, vhat, dsq = fused_update(
+                    pflat, state.h, state.vhat, F.nabla_f32(out.comm))
+                comm = F.record_progress(out.comm, dsq, k)
             new_state = DistTrainState(step=k + 1, params=params, h=h,
                                        vhat=vhat, comm=comm)
             metrics = {"loss": jnp.mean(out.losses), "dtheta_sq": dsq,
@@ -693,12 +705,13 @@ def make_cohort_train_step(cfg: ModelConfig, hp: TrainHParams, m: int):
                 strategy, layout, state.server, rows, state.params,
                 state.params_flat, batch, k, cohort, m_total=m,
                 vgrad=vgrad, vgrad_per=vgrad_per, fuse_evals=True)
-            theta, h, vhat, dsq = kops.fused_amsgrad_flat(
-                state.params_flat, state.h, state.vhat,
-                out.server.nabla.astype(jnp.float32), hp.lr,
-                b1=hp.b1, b2=hp.b2, eps=hp.eps)
-            theta = layout.cast_roundtrip(theta)
-            server = F.record_progress(out.server, dsq, k)
+            with jax.named_scope("cada.server_update"):
+                theta, h, vhat, dsq = kops.fused_amsgrad_flat(
+                    state.params_flat, state.h, state.vhat,
+                    out.server.nabla.astype(jnp.float32), hp.lr,
+                    b1=hp.b1, b2=hp.b2, eps=hp.eps)
+                theta = layout.cast_roundtrip(theta)
+                server = F.record_progress(out.server, dsq, k)
             new_state = CohortTrainState(
                 step=k + 1, params=layout.unpack(theta), h=h, vhat=vhat,
                 server=server, params_flat=theta)

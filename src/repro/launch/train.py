@@ -325,9 +325,11 @@ def run_mesh(cfg, rule, args) -> MeshRun:
         raise SystemExit(
             f"--global-batch {args.global_batch} must divide into "
             f"local_steps*workers = {h}*{m} per-local-step slices")
-    # telemetry: per-step train spans on the wall clock + a comm ledger
-    # fed from device-side metric buffers fetched every --metrics-every
-    # steps (same cadence contract as the cohort driver)
+    # telemetry: per-step train spans on the wall clock (``dispatch``, the
+    # call that enqueues the step; ``wait``, the fetch of its scalars on
+    # the steps that log them) + a comm ledger fed from device-side metric
+    # buffers fetched every --metrics-every steps (same cadence contract
+    # as the cohort driver)
     obs_on = bool(args.trace or args.metrics_out or args.metrics_prom)
     tracer = None
     ledger = None
@@ -360,7 +362,7 @@ def run_mesh(cfg, rule, args) -> MeshRun:
         for i in range(args.steps):
             t_step = time.time()
             batch = worker_split({"tokens": batches[i]}, m, local_steps=h)
-            with tr.span("train_step", track="train", args={"step": i}):
+            with tr.span("dispatch", track="train", args={"step": i}):
                 state, mets = step(state, batch)
             if obs_on:
                 obs_buf.append(mets)
@@ -368,9 +370,11 @@ def run_mesh(cfg, rule, args) -> MeshRun:
                     drain_obs()
             if i % args.log_every == 0 or i == args.steps - 1:
                 # the scalars, plus the per-worker upload mask as a list
-                row = {k: float(v) for k, v in mets.items()
-                       if np.ndim(v) == 0}
-                row["upload_mask"] = np.asarray(mets["upload_mask"]).tolist()
+                with tr.span("wait", track="train", args={"step": i}):
+                    row = {k: float(v) for k, v in mets.items()
+                           if np.ndim(v) == 0}
+                    row["upload_mask"] = np.asarray(
+                        mets["upload_mask"]).tolist()
                 row["step"] = i
                 # fetching the scalars waited for the step: step_s is its
                 # host-clock time, the first step's compile included

@@ -11,8 +11,9 @@ Three pieces, importable without JAX:
   dependency-free schema validator.
 
 See ``src/repro/obs/README.md`` for the span taxonomy, sink formats, and
-the overhead contract (disabled <2%, enabled <10% steps/sec — pinned by
-the ``obs_overhead`` arm of ``BENCH_cada.json``).
+the overhead contract (disabled <2%, enabled <10% steps/sec): tracing off
+is measured by every chip benchmark run (``bench/``, untraced), tracing on
+by the traced run against it, recorded in ``PERF.md`` §7.
 """
 
 from .trace import NULL, NullTracer, Tracer, as_tracer

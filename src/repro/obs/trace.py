@@ -17,15 +17,22 @@ A *track* is a named horizontal lane in the timeline (one per simulated
 worker, one for the server, one for the cohort pipeline, ...). Tracks are
 created on first use and keep insertion order in the exported view.
 
+Profiler clock
+--------------
+A wall-clock span of an enabled :class:`Tracer` also enters
+``jax.profiler.TraceAnnotation("<track>.<name>")``, so under a
+``jax.profiler`` trace it lands on the host plane, on the device ops'
+clock: a device idle gap can be put down to the host span that holds it.
+JAX is imported on the first such span, never at import.
+
 Disabled path
 -------------
 ``NULL`` is a module-level :class:`NullTracer` singleton: every method is
 a no-op, ``bool(NULL)`` is ``False`` (so ``if tracer:`` guards skip
 argument construction entirely), and ``NULL.span(...)`` returns one
 reusable null context manager — no allocation, no clock read. Hot loops
-take ``trace=None`` and normalize via :func:`as_tracer`; the overhead
-contract (<2% steps/sec disabled) is pinned by the ``obs_overhead``
-arm in ``BENCH_cada.json``.
+take ``trace=None`` and normalize via :func:`as_tracer`. Where the cost
+is measured: see the overhead contract in ``README.md``.
 """
 
 from __future__ import annotations
@@ -86,7 +93,7 @@ def as_tracer(trace) -> "Tracer | NullTracer":
 class _Span:
     """Context manager recording one wall-clock span on exit."""
 
-    __slots__ = ("_tr", "name", "track", "cat", "args", "_t0")
+    __slots__ = ("_tr", "name", "track", "cat", "args", "_t0", "_ann")
 
     def __init__(self, tr, name, track, cat, args):
         self._tr = tr
@@ -96,11 +103,15 @@ class _Span:
         self.args = args
 
     def __enter__(self):
+        from jax.profiler import TraceAnnotation
+        self._ann = TraceAnnotation(f"{self.track}.{self.name}")
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        self._ann.__exit__(None, None, None)
         tr = self._tr
         tr._events.append(("X", self.name, self.track, self.cat,
                            self._t0 - tr._epoch, t1 - self._t0, self.args))

@@ -425,3 +425,50 @@ def test_obs_smoke_traced_m10k_cohort(tmp_path):
                        meta={"runtime": "cohort", "m": m, "c": c})
     from repro.obs.export import main
     assert main(["--validate", str(path)]) == 0
+
+
+# ------------------------------------------------- the profiler's clock
+
+def test_tracer_span_lands_on_the_profiler_host_plane(tmp_path):
+    """An enabled Tracer's wall-clock span is also a profiler annotation:
+    under ``jax.profiler`` it appears on a ``/host:`` plane, named
+    ``<track>.<name>``, on the device ops' clock."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    tr = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("gather", track="pipeline"):
+            jax.block_until_ready(jnp.ones(8) + 1)
+    finally:
+        jax.profiler.stop_trace()
+    assert tr.aggregate("pipeline")["gather"]["count"] == 1
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = [e.name for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events]
+    assert names.count("pipeline.gather") == 1
+
+
+def test_run_mesh_traces_dispatch_and_wait(tmp_path):
+    """The launcher's mesh loop times the step's enqueue (``dispatch``,
+    every step) apart from the fetch of its scalars (``wait``, on the
+    steps it logs: 0 and the last of 3 at ``--log-every 2``)."""
+    import repro.configs as RC
+    from repro.launch.train import build_parser, rule_from_args, run_mesh
+
+    path = tmp_path / "mesh_trace.json"
+    args = build_parser().parse_args([
+        "--arch", "stablelm-1.6b", "--smoke", "--rule", "cada2",
+        "--steps", "3", "--global-batch", "4", "--seq", "16",
+        "--workers", "2", "--log-every", "2", "--trace", str(path)])
+    run_mesh(RC.get_smoke_config(args.arch), rule_from_args(args), args)
+    spans = [e for e in json.loads(path.read_text())["traceEvents"]
+             if e["ph"] == "X"]
+    steps = lambda name: [e["args"]["step"] for e in spans  # noqa: E731
+                          if e["name"] == name]
+    assert steps("dispatch") == [0, 1, 2]
+    assert steps("wait") == [0, 2]
+    assert not steps("train_step")
