@@ -698,13 +698,14 @@ def flat_comm_round(strategy, layout: FlatLayout, comm: FlatCommState,
             # intermediate lets GSPMD gather the full plane before reducing
             # it.
             wire = shard.constrain_worker(wire)
-        # Order-fixed row accumulation (kops.eq3_row_mean): masked zero
-        # rows are exact no-ops, so this dense masked mean is BIT-IDENTICAL
-        # to the cohort plane's C-row sum below (flat_cohort_round) — the
-        # parity the cohort tests pin.
-        nabla = (comm.nabla.astype(jnp.float32)
-                 + kops.eq3_row_mean(wire, m, shard=shard)
-                 ).astype(comm.nabla.dtype)
+        # Order-fixed row accumulation (kops.eq3_row_mean, one pass over
+        # the wire plane into ∇̄): masked zero rows are exact no-ops, so
+        # this dense masked mean is BIT-IDENTICAL to the cohort plane's
+        # C-row sum below (flat_cohort_round) — the parity the cohort
+        # tests pin.
+        nabla = kops.eq3_row_mean(wire, m, comm.nabla.astype(jnp.float32),
+                                  shard=shard, interpret=interpret
+                                  ).astype(comm.nabla.dtype)
         if shard is not None:
             nabla = shard.constrain_server(nabla)
         worker_grads = (wg32 + wire.astype(jnp.float32)
@@ -1131,8 +1132,10 @@ def flat_cohort_round(strategy, layout: FlatLayout,
         # ∇̄ += Σ_cohort δ_m / M — the incremental aggregate; the (M-C)
         # offline rows would contribute exact zeros, so the dense masked
         # mean is reproduced bit-for-bit without ever materializing it.
-        nabla = (server.nabla.astype(jnp.float32)
-                 + kops.eq3_row_mean(wire, m_total)).astype(server.nabla.dtype)
+        nabla = kops.eq3_row_mean(wire, m_total,
+                                  server.nabla.astype(jnp.float32),
+                                  interpret=interpret
+                                  ).astype(server.nabla.dtype)
         worker_grads = (wg32 + wire.astype(jnp.float32)
                         ).astype(rows["worker_grads"].dtype)
 

@@ -216,3 +216,56 @@ def diff_sq_norm_flat(a, b, *, interpret=False):
         interpret=interpret,
     )(a.reshape(shape2d), b.reshape(shape2d))
     return out[0, 0]
+
+
+ROW_MEAN_BLOCK_BYTES = 1 << 20  # fp32 bytes of one (rows, cols) plane block
+
+
+def descending_row_sum(row, rows: int):
+    """Σ_i row(i) over ``rows`` static rows, in DESCENDING order from +0.0:
+    the order that makes all-zero rows exact no-ops (see
+    ``ops.eq3_row_mean``). The +0.0 start is the first row with −0.0
+    mapped to +0.0, since XLA folds an explicit ``0.0 + x`` to ``x``; a
+    single row is taken as it is, which is what that fold gives."""
+    acc = row(rows - 1)
+    if rows > 1:
+        acc = jnp.where(acc == 0.0, 0.0, acc)
+    for i in range(rows - 2, -1, -1):
+        acc = acc + row(i)
+    return acc
+
+
+def _row_mean_kernel(plane_ref, *refs, rows: int, m_total: int):
+    """Eq. (3)'s aggregate over one column block of the (rows, n) plane,
+    from static row slices of the VMEM block; with a base block, the
+    result is added to it."""
+    *base, out_ref = refs
+    acc = descending_row_sum(lambda i: plane_ref[i:i + 1, :], rows) / m_total
+    out_ref[...] = base[0][...] + acc if base else acc
+
+
+def row_mean_flat(plane, m_total: int, base=None, *, interpret=False):
+    """(n,) ``base + Σ_rows(plane) / m_total`` over an fp32 (rows, n)
+    plane in one pass, rows summed in descending order.
+
+    The plane is read in its own (rows, cols) layout — no reshape, so the
+    compiler makes no relayout copy of a few-row plane — and the base is
+    updated in place. A partial last column block is masked on the write.
+    """
+    rows, n = plane.shape
+    cols = max(LANES, ROW_MEAN_BLOCK_BYTES // (4 * rows) // LANES * LANES)
+    spec = pl.BlockSpec((1, cols), lambda j: (0, j))
+    operands = [plane] + ([base.reshape(1, n)] if base is not None else [])
+    out = pl.pallas_call(
+        partial(_row_mean_kernel, rows=rows, m_total=m_total),
+        grid=(pl.cdiv(n, cols),),
+        in_specs=[pl.BlockSpec((rows, cols), lambda j: (0, j))]
+        + [spec] * (len(operands) - 1),
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        input_output_aliases={1: 0} if base is not None else {},
+        interpret=interpret,
+    )(*operands)
+    return out.reshape(n)

@@ -123,38 +123,42 @@ def diff_sq_norm_flat(a, b, *, interpret=None):
     return _cu.diff_sq_norm_flat(ap, bp, interpret=interpret)
 
 
-@partial(jax.jit, static_argnames=("m_total", "shard"))
-def eq3_row_mean(plane, m_total, *, shard=None):
-    """Eq. (3) server aggregate increment: Σ_rows(plane) / m_total.
+@partial(jax.jit, static_argnames=("m_total", "shard", "interpret"))
+def eq3_row_mean(plane, m_total, base=None, *, shard=None, interpret=None):
+    """Eq. (3) server aggregate increment: Σ_rows(plane) / m_total, added
+    to ``base`` (the server's ∇̄, in fp32) when one is given.
 
     The row reduction is an ORDER-FIXED sequential accumulation over
-    rows in DESCENDING row order (``fori_loop``), not XLA's tree
-    reduction.  A fixed sequential order makes the result invariant to
-    dropping all-zero rows: a masked dense ``(M, n)`` wire plane and the
-    gathered ``(C, n)`` cohort plane holding only its nonzero rows (in
-    ascending worker order) produce BIT-IDENTICAL fp32 aggregates, which
-    is what lets the cohort-virtualized worker plane stay a drop-in for
-    the dense plane.  (+0.0 addends are exact no-ops:
-    the accumulator starts at +0.0 and IEEE-754 addition can only reach
-    −0.0 from two −0.0 operands, so skipping zero rows never changes a
-    bit.)  Pass ``m_total`` = the FULL worker count M even when ``plane``
-    has only C cohort rows.
+    rows in DESCENDING row order, not XLA's tree reduction: a chain of
+    static row slices, unrolled at trace time (the row count is a static
+    shape), so it is one elementwise pass over the plane — on the TPU the
+    Pallas kernel ``row_mean_flat``, elsewhere the same chain in jnp.  A
+    fixed sequential order makes the result invariant to dropping
+    all-zero rows: a masked dense ``(M, n)`` wire plane and the gathered
+    ``(C, n)`` cohort plane holding only its nonzero rows (in ascending
+    worker order) produce BIT-IDENTICAL fp32 aggregates, which is what
+    lets the cohort-virtualized worker plane stay a drop-in for the dense
+    plane.  (+0.0 addends are exact no-ops: the sum starts from +0.0 and
+    IEEE-754 addition can only reach −0.0 from two −0.0 operands, so
+    skipping zero rows never changes a bit; ``descending_row_sum`` says
+    how the +0.0 start is written.)  Pass ``m_total`` = the FULL worker
+    count M even when ``plane`` has only C cohort rows.
 
     ``shard``: under a sharded worker axis a cross-device sequential
     order is not expressible — fall back to the tree reduction (the
     sharded trainer plane is never the cohort parity oracle).
     """
     plane = plane.astype(jnp.float32)
-    if shard is not None:
-        return jnp.sum(plane, axis=0) / m_total
-
-    rows = plane.shape[0]
-
-    def body(i, acc):
-        return acc + plane[rows - 1 - i]
-
-    zero = jnp.zeros(plane.shape[1:], jnp.float32)
-    return jax.lax.fori_loop(0, rows, body, zero) / m_total
+    if shard is None:
+        pallas, interpret = _use_pallas(interpret)
+        if pallas:
+            return _cu.row_mean_flat(plane, m_total, base,
+                                     interpret=interpret)
+        mean = _cu.descending_row_sum(lambda i: plane[i],
+                                      plane.shape[0]) / m_total
+    else:
+        mean = jnp.sum(plane, axis=0) / m_total
+    return mean if base is None else base + mean
 
 
 @partial(jax.jit, static_argnames=("interpret", "shard"))

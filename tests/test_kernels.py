@@ -1,5 +1,7 @@
 """Per-kernel allclose vs the ref.py pure-jnp oracles, swept over shapes and
 dtypes (interpret=True executes the kernel body in Python on CPU)."""
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -65,6 +67,52 @@ def test_diff_sq_norm_matches_ref(rng):
         d = ops.diff_sq_norm_flat(a, b, interpret=True)
         np.testing.assert_allclose(float(d), float(ref.diff_sq_norm_ref(a, b)),
                                    rtol=1e-5)
+
+
+@partial(jax.jit, static_argnames=("m_total",))
+def _eq3_row_mean_loop(plane, m_total, base=None):
+    """Oracle: eq. (3)'s order-fixed row sum as a ``fori_loop`` from +0.0
+    over the rows in descending order, over ``m_total``, added to
+    ``base`` in the same program when one is given."""
+    plane = plane.astype(jnp.float32)
+    rows = plane.shape[0]
+    zero = jnp.zeros(plane.shape[1:], jnp.float32)
+    mean = jax.lax.fori_loop(
+        0, rows, lambda i, acc: acc + plane[rows - 1 - i], zero) / m_total
+    return mean if base is None else base + mean
+
+
+@pytest.mark.parametrize("interpret", [None, True], ids=["jnp", "kernel"])
+@pytest.mark.parametrize("masked", [False, True])
+@pytest.mark.parametrize("rows", [1, 2, 4, 64])
+def test_eq3_row_mean_matches_order_fixed_loop(rng, rows, masked, interpret):
+    """The unrolled chain of static row slices — in jnp and in the Pallas
+    kernel's body — gives the loop's bits, zero signs included, over
+    planes with −0.0 entries and, when ``masked``, half the rows zeroed
+    to +0.0 as the upload mask does; so does its sum into a base plane.
+    The jnp form's program has no loop left."""
+    n = 50_000
+    x = rng.normal(size=(rows, n)) * 10.0 ** rng.uniform(-3, 3, (rows, n))
+    x[:, :64] = -0.0                                  # an all −0.0 column
+    x[rng.uniform(size=(rows, n)) < 0.05] = -0.0      # scattered −0.0
+    if masked:
+        x[np.arange(rows) % 2 == 0] = 0.0
+    plane = jnp.asarray(x, jnp.float32)
+    base = _rand(rng, n, scale=1e-3)
+    m_total = rows + 3
+    want = _eq3_row_mean_loop(plane, m_total)
+    got = ops.eq3_row_mean(plane, m_total, interpret=interpret)
+    np.testing.assert_array_equal(np.asarray(got).view(np.int32),
+                                  np.asarray(want).view(np.int32))
+    got = ops.eq3_row_mean(plane, m_total, base, interpret=interpret)
+    want = _eq3_row_mean_loop(plane, m_total, base)
+    np.testing.assert_array_equal(np.asarray(got).view(np.int32),
+                                  np.asarray(want).view(np.int32))
+    if interpret is None:
+        lowered = jax.jit(ops.eq3_row_mean, static_argnums=1).lower(
+            plane, m_total)
+        assert "while" not in lowered.as_text()
+        assert "while" not in lowered.compile().as_text()
 
 
 def test_pytree_fused_update_roundtrip(rng):

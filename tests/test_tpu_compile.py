@@ -88,6 +88,19 @@ def test_batched_sq_norm_flat_compiles(one_chip, n_flat):
     assert "tpu_custom_call" in txt
 
 
+def test_eq3_row_mean_compiles_to_one_pass(one_chip, n_flat):
+    """Eq. (3)'s order-fixed row sum over the M=4 wire plane, into ∇̄,
+    compiles to the one-pass kernel: no loop, and no row copied out by a
+    dynamic slice."""
+    plane = _sds(one_chip, (M, n_flat))
+    nabla = _sds(one_chip, (n_flat,))
+    txt = _compiled_text(
+        lambda w, b: ops.eq3_row_mean(w, M, b, interpret=False), plane, nabla)
+    assert "tpu_custom_call" in txt
+    assert "while" not in txt
+    assert "dynamic-slice" not in txt
+
+
 def test_flash_attention_kernel_compiles(one_chip):
     """stablelm-1.6b's heads (32 × 64) at a 2,048-token sequence."""
     cfg = chip_config()
