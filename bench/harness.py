@@ -1,11 +1,19 @@
 """The system under test, driven as its launcher drives it.
 
-``Program`` builds the trainer step of a one-chip cell from the program's
-own entry point: ``distributed.trainer.make_train_step`` under
-``jax.jit(..., donate_argnums=(0,))`` over M simulated workers, what
-``launch/train.run_mesh`` builds for ``--workers M``. The state comes from
-the seed in one jitted call on the device, through the program's
-``init_train_state``. Batches go through ``worker_split``.
+``Program`` builds a cell's trainer step from the program's own entry
+points, as ``launch/train.run_mesh`` builds it:
+
+* one chip: ``distributed.trainer.make_train_step`` under
+  ``jax.jit(..., donate_argnums=(0,))`` over M simulated workers, what
+  ``run_mesh`` builds for ``--workers M``;
+* C > 1 chips: ``trainer.jit_train_step`` on the (C, 1) ``(data, model)``
+  mesh of the cell's own chips, one worker per chip on ``data``, with the
+  flat state ZeRO'd over the traffic's ``state_fsdp_axes``: what
+  ``run_mesh`` builds without ``--workers`` on a host of C chips.
+
+The state comes from the seed in one jitted call on the device, through the
+program's ``init_train_state``, laid out as the compiled step takes it.
+Batches go through ``worker_split``.
 
 This module imports the program; the reference (``refstep``,
 ``references/``) does not.
@@ -54,33 +62,24 @@ class Program:
     """One cell's compiled step, its state and its feed."""
 
     def __init__(self, cell, devices):
-        from jax.sharding import SingleDeviceSharding
-
         from repro.core.rules import CommRule
         from repro.distributed import trainer as T
 
-        if cell.chips != 1:
-            raise ValueError(f"{cell.name}: the harness drives one chip; "
-                             f"the cell asks for {cell.chips}")
         tr = cell.traffic
         self.cell = cell
         self.cfg = program_config(cell.config)
         self.vocab = self.cfg.vocab
-        rule = tr["rule"]
-        self.hp = T.TrainHParams(
-            rule=CommRule(kind=rule["kind"], c=rule["c"],
-                          d_max=rule["d_max"], max_delay=rule["max_delay"]),
-            lr=float(tr["lr"]))
+        rule = CommRule(kind=tr["rule"]["kind"], c=tr["rule"]["c"],
+                        d_max=tr["rule"]["d_max"],
+                        max_delay=tr["rule"]["max_delay"])
         self.m = cell.workers
-        self.shards = 1
-        self.sharding = SingleDeviceSharding(devices[0])
-        self.jitted = jax.jit(T.make_train_step(self.cfg, self.hp, self.m),
-                              donate_argnums=(0,))
-        self.init = jax.jit(partial(T.init_train_state, self.cfg, self.hp,
-                                    self.m), out_shardings=self.sharding)
-        layout = T.flat_layout(self.cfg)
-        f32 = [jnp.float32] * len(layout.dtypes)
+        if cell.chips == 1:
+            self._one_chip(T, rule, devices[0])
+        else:
+            self._mesh(T, rule, devices)
         b1 = self.hp.b1
+        layout = T.flat_layout(self.cfg, self.shards)
+        f32 = [jnp.float32] * len(layout.dtypes)
         self._grad_norms = jax.jit(
             lambda h: leaf_norms(layout.unpack(h.astype(jnp.float32), f32))
             / (1.0 - b1))
@@ -89,14 +88,64 @@ class Program:
         # the state holds it: fused into the difference, the compiler may
         # keep the normal draws in float32 and skip that rounding.
         self._init_params = jax.jit(partial(init_params, self.cfg),
-                                    out_shardings=self.sharding)
+                                    out_shardings=self.params_sharding)
         self.compiled = None
+
+    def _one_chip(self, T, rule, device):
+        """M simulated workers on one device, an unsharded flat plane."""
+        from jax.sharding import SingleDeviceSharding
+
+        self.hp = T.TrainHParams(rule=rule, lr=float(self.cell.traffic["lr"]))
+        self.shards = 1
+        one = SingleDeviceSharding(device)
+        self.state_sharding = self.batch_sharding = self.params_sharding = one
+        self.jitted = jax.jit(T.make_train_step(self.cfg, self.hp, self.m),
+                              donate_argnums=(0,))
+        self.init = jax.jit(partial(T.init_train_state, self.cfg, self.hp,
+                                    self.m), out_shardings=one)
+
+    def _mesh(self, T, rule, devices):
+        """One worker per chip on the ``data`` axis of the (C, 1) mesh over
+        the cell's chips; the state and batch laid out as
+        ``jit_train_step`` compiles for (``place_train_state``'s
+        shardings)."""
+        from jax.sharding import AxisType
+
+        from repro.distributed.sharding import to_named
+        from repro.launch.mesh import DATA, MODEL
+
+        cell, chips = self.cell, self.cell.chips
+        if self.m != chips:
+            raise ValueError(
+                f"{cell.name}: {self.m} workers on {chips} chips; the "
+                f"harness runs one worker per chip")
+        mesh = jax.make_mesh((chips, 1), (DATA, MODEL),
+                             axis_types=(AxisType.Auto,) * 2,
+                             devices=list(devices)[:chips])
+        self.hp = T.TrainHParams(
+            rule=rule, lr=float(cell.traffic["lr"]),
+            state_fsdp_axes=tuple(cell.traffic["state_fsdp_axes"]))
+        make, sspecs, m = T.jit_train_step(self.cfg, mesh, self.hp)
+        self.shards = T.flat_state_shards(self.cfg, mesh, self.hp)
+        self.state_sharding = to_named(mesh, sspecs)
+        self.params_sharding = self.state_sharding.params
+        rows = cell.global_batch
+        tokens = jax.ShapeDtypeStruct((rows, cell.seq + 1), jnp.int32)
+        batch_sds = T.worker_split_abstract({"tokens": tokens}, m)
+        spec_for = T.train_batch_specs(mesh)
+        self.batch_sharding = {k: to_named(mesh, spec_for(k, v.ndim))
+                               for k, v in batch_sds.items()}
+        self.jitted = make(batch_sds)
+        self.init = jax.jit(
+            partial(T.init_train_state, self.cfg, self.hp, m,
+                    shards=self.shards),
+            out_shardings=self.state_sharding)
 
     def batch(self, seed: int, step: int):
         from repro.distributed.trainer import worker_split
         toks = feed.step_tokens(self.cell.traffic, self.vocab, seed, step)
         return jax.device_put(worker_split({"tokens": toks}, self.m),
-                              self.sharding)
+                              self.batch_sharding)
 
     def new_state(self, seed: int):
         return self.init(seed_key(seed))

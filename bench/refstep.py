@@ -19,7 +19,11 @@ second half of every worker's rows and takes the mean over the rest;
 ``"gate_always"`` is a gate that never skips (every worker uploads every
 step); ``"rhs_scaled"`` takes the RHS ten times too large;
 ``"ring_latest"`` evaluates the second gradient at θ^{k-1} whatever τ_m,
-as a stale-iterate ring indexed wrongly for τ_m >= 2 would.
+as a stale-iterate ring indexed wrongly for τ_m >= 2 would;
+``"no_exchange"`` leaves out the exchange between chips of a step with one
+worker per chip and the server state split over them: the leaves laid end
+to end, padded to a multiple of 8 · M and cut into M equal blocks, block s
+of the aggregate takes worker s's upload alone (over M).
 """
 from __future__ import annotations
 
@@ -34,7 +38,7 @@ from bench import feed
 
 UPDATE_STEPS = 3        # θ^3 − θ^0 is compared
 FAULTS = ("state_unchanged", "half_batch", "gate_always", "rhs_scaled",
-          "ring_latest")
+          "ring_latest", "no_exchange")
 
 
 @dataclass
@@ -110,6 +114,24 @@ def reference_run(model, cfg: dict, traffic: dict, seed: int, steps: int,
     def add_scaled(acc, x, w):
         return jax.tree.map(lambda a, b: a + w * b, acc, x)
 
+    @partial(jax.jit, static_argnums=3)
+    def add_block(acc, x, w, s):
+        """``acc + w · x`` on block ``s`` of ``no_exchange``'s M blocks."""
+        leaves, tree = jax.tree.flatten(x)
+        n = sum(b.size for b in leaves)
+        block = (n + (-n) % (8 * m)) // m
+        out, off = [], 0
+        for a, b in zip(jax.tree.leaves(acc), leaves):
+            pos = off + jnp.arange(b.size, dtype=jnp.int32).reshape(b.shape)
+            out.append(a + jnp.where(pos // block == s, w * b, 0.0))
+            off += b.size
+        return jax.tree.unflatten(tree, out)
+
+    def aggregate(acc, x, w, worker):
+        if fault == "no_exchange":
+            return add_block(acc, x, w, worker)
+        return add_scaled(acc, x, w)
+
     @jax.jit
     def amsgrad(theta, h, v, g):
         h = jax.tree.map(lambda a, b: b1 * a + (1 - b1) * b, h, g)
@@ -148,8 +170,8 @@ def reference_run(model, cfg: dict, traffic: dict, seed: int, steps: int,
         if kind == "always":
             upload = [True] * m
             nabla = zeros(theta)
-            for gw in fresh:
-                nabla = add_scaled(nabla, gw, 1.0 / m)
+            for w, gw in enumerate(fresh):
+                nabla = aggregate(nabla, gw, 1.0 / m, w)
             lhs_all = rhs_all = forced = None
         else:
             rhs = c / d_max * float(diff_hist.sum())
@@ -166,7 +188,7 @@ def reference_run(model, cfg: dict, traffic: dict, seed: int, steps: int,
             for w in range(m):
                 if upload[w]:
                     delta = add_scaled(fresh[w], wg[w], -1.0)
-                    nabla = add_scaled(nabla, delta, 1.0 / m)
+                    nabla = aggregate(nabla, delta, 1.0 / m, w)
                     wg[w] = add_scaled(wg[w], delta, 1.0)
                     point[w] = theta
                     tau[w] = 1

@@ -13,10 +13,12 @@ ran and its instruction names are the trace's.
 
 ``op_scopes`` maps every instruction of every computation. An instruction
 the compiler made with no metadata at all (a copy, a split reduction, the
-loop a gather became) takes the phase of the fusion body it calls, else of
-the instruction that calls its computation (a ``while`` or a fusion), else
-of its operands, else of its users; one whose ``op_name`` names no phase
-stays unscoped.
+loop a gather became), or with an instruction's name for its whole
+``op_name`` (``convert.57``: a pass of the partitioned four-chip step names
+what it makes after what it replaced, with no name stack), takes the phase
+of the fusion body it calls, else of the instruction that calls its
+computation (a ``while`` or a fusion), else of its operands, else of its
+users; one whose ``op_name`` names no phase stays unscoped.
 """
 from __future__ import annotations
 
@@ -30,6 +32,7 @@ from bench import traces
 PREFIX = "cada."
 _SCOPE = re.compile(r"cada\.([A-Za-z_]\w*)")
 _OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_INSTRUCTION_NAME = re.compile(r"[a-z][\w\-]*\.\d+")
 _CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
 _CALLEE = re.compile(r"\b(?:calls|body|condition|to_apply)=%?([\w.\-]+)"
                      r"|\b(?:branch|called)_computations=\{([^}]*)\}")
@@ -85,8 +88,10 @@ def op_scopes(hlo_text: str) -> dict:
         members.setdefault(comp, []).append(name)
         comp_of[name] = comp
         op_name = _OP_NAME.search(line)
-        scope[name] = innermost(op_name.group(1)) if op_name else None
-        if op_name is None:
+        if op_name and not _INSTRUCTION_NAME.fullmatch(op_name.group(1)):
+            scope[name] = innermost(op_name.group(1))
+        else:
+            scope[name] = None
             bare.append(name)
         c = _CALLS.search(line)
         if c:
@@ -150,26 +155,33 @@ def scope_seconds(trace: dict, op_map: dict) -> tuple:
             unscoped / n * 1e-9)
 
 
-def _abstract(tree, sharding):
+def _abstract(tree, shardings):
+    """``tree`` as ShapeDtypeStructs laid out by ``shardings``: one
+    sharding for every leaf, or a tree of them shaped as ``tree``."""
     import jax
+    if isinstance(shardings, jax.sharding.Sharding):
+        shardings = jax.tree.map(lambda _: shardings, tree)
     return jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding,
-                                       weak_type=getattr(x, "weak_type",
-                                                         False)), tree)
+        lambda x, s: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=s,
+            weak_type=getattr(x, "weak_type", False)), tree, shardings)
 
 
 def step_scopes(view) -> dict:
     """The instruction -> phase map of the cell's compiled step:
     ``Program(cell, devices).jitted.lower(<state and batch as
-    ShapeDtypeStructs on the program's sharding>).compile()``."""
+    ShapeDtypeStructs laid out as the program lays them out>).compile()``.
+    On several chips that is the partitioned module, the collectives that
+    the SPMD partitioner put in among its instructions."""
     import jax
 
     from bench.harness import Program
     from bench.refstep import seed_key
     t = time.perf_counter()
     prog = Program(view.cell, jax.devices()[:view.chips])
-    state = _abstract(jax.eval_shape(prog.init, seed_key(0)), prog.sharding)
-    batch = _abstract(prog.batch(0, 0), prog.sharding)
+    state = _abstract(jax.eval_shape(prog.init, seed_key(0)),
+                      prog.state_sharding)
+    batch = _abstract(prog.batch(0, 0), prog.batch_sharding)
     text = prog.jitted.lower(state, batch).compile().as_text()
     op_map = op_scopes(text)
     log(f"scopes: step rebuilt in {time.perf_counter() - t:.2f} s, "

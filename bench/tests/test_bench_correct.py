@@ -39,9 +39,14 @@ TINY_LIMITS = {"loss_gap": 4e-3, "grad_gap": 4e-3, "update_gap": 6e-3,
                "forced_skips": 0.0, "gate_audit": 0.0, "rhs_audit": 1e-4}
 
 
-@pytest.fixture(scope="module")
-def tiny_root(tmp_path_factory):
-    root = tmp_path_factory.mktemp("tiny")
+# the tiny cells: name -> the traffic mix each takes (at seq 32, lr 1e-3)
+TINY_CELLS = {"tiny.cada2": "cada2-m4", "tiny.always": "always-m4",
+              "tiny.cada2-dp4": "cada2-dp4"}
+
+
+def write_tiny_root(root):
+    """A checkout under ``root`` whose cells are the tiny configuration
+    under each mix of ``TINY_CELLS``, with the tiny limits."""
     bench = root / "bench"
     shutil.copytree(ROOT / "bench", bench,
                     ignore=shutil.ignore_patterns("tests", "__pycache__",
@@ -56,19 +61,24 @@ def tiny_root(tmp_path_factory):
     b["configs"] = [{"name": "tiny", "source": "test", "reduced": [],
                      "file": "bench/configs/tiny.json", "why": "test"}]
     b["workloads"] = []
-    for kind in ("cada2", "always"):
-        t = json.loads((bench / "traffic" / f"{kind}-m4.json").read_text())
+    for name, mix in TINY_CELLS.items():
+        t = json.loads((bench / "traffic" / f"{mix}.json").read_text())
         t.update(seq=32, lr=1e-3)
-        (bench / "traffic" / f"tiny-{kind}.json").write_text(json.dumps(t))
-        b["workloads"].append({"name": f"tiny.{kind}", "config": "tiny",
-                               "traffic": f"tiny-{kind}", "chips": 1,
+        (bench / "traffic" / f"tiny-{mix}.json").write_text(json.dumps(t))
+        b["workloads"].append({"name": name, "config": "tiny",
+                               "traffic": f"tiny-{mix}", "chips": t["chips"],
                                "why": "test"})
-        (bench / "limits" / f"tiny.{kind}.json").write_text(
+        (bench / "limits" / f"{name}.json").write_text(
             json.dumps({"check_steps": TINY_STEPS, "limits": TINY_LIMITS}))
     for m in b["per_layer"]:
-        m["workloads"] = ["tiny.cada2", "tiny.always"]
+        m["workloads"] = list(TINY_CELLS)
     (root / "BENCHMARK.json").write_text(json.dumps(b))
     return root
+
+
+@pytest.fixture(scope="module")
+def tiny_root(tmp_path_factory):
+    return write_tiny_root(tmp_path_factory.mktemp("tiny"))
 
 
 def _run(root, workload, seed=2 ** 31 + 3, trace=0, hook=None):
@@ -78,31 +88,18 @@ def _run(root, workload, seed=2 ** 31 + 3, trace=0, hook=None):
 
 
 def _state_unchanged(prog):
+    """A step that returns the state it was given."""
     real = prog.jitted
-
-    class Broken:
-        def lower(self, state, batch):
-            inner = real.lower(state, batch).compile()
-
-            class Compiled:
-                def __call__(self, st, b):
-                    _, mets = inner(jax.tree.map(jnp.copy, st), b)
-                    return st, mets
-
-                def memory_analysis(self):
-                    return inner.memory_analysis()
-
-            return type("L", (), {"compile": lambda _s: Compiled()})()
-
-    prog.jitted = Broken()
+    prog.jitted = jax.jit(lambda s, b: (s, real(jax.tree.map(jnp.copy, s),
+                                                 b)[1]))
 
 
 def _half_batch(prog):
-    from repro.distributed.trainer import make_train_step
-    step = make_train_step(prog.cfg, prog.hp, prog.m)
+    """Half of every worker's rows left out, the mean taken over the rest."""
+    real = prog.jitted
     prog.jitted = jax.jit(
-        lambda s, b: step(s, {"tokens": b["tokens"][:, : b["tokens"].shape[1]
-                                                    // 2]}),
+        lambda s, b: real(s, {"tokens": b["tokens"][:, : b["tokens"].shape[1]
+                                                     // 2]}),
         donate_argnums=(0,))
 
 

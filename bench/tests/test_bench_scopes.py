@@ -42,6 +42,7 @@ ENTRY %main.9 (Arg_0.1: f32[8]) -> f32[8] {
   %constant.8 = f32[] constant(0)
   %wrapped_broadcast = f32[8]{0} fusion(f32[] %constant.8), kind=kLoop, calls=%wrapped_broadcast_computation
   %while.8 = (s32[], f32[8]{0}) while((s32[], f32[8]{0}) %tuple.2), condition=%cond, body=%body, metadata={op_type="while" op_name="jit(step_flat)/cada.eq3/jit(eq3_row_mean)/while"}
+  %reshape.11 = f32[8]{0} reshape(f32[8]{0} %fusion.751), metadata={op_name="convert.57"}
   ROOT %multiply.9 = f32[8]{0} multiply(f32[8]{0} %copy.7, f32[8]{0} %add.6)
 }
 """
@@ -64,6 +65,7 @@ def op_map():
     ("wrapped_broadcast", "cada.gate"),              # no metadata: its body
     ("multiply.9", "cada.eq3"),                      # no metadata: operands
     ("dynamic-update-slice.10", "cada.eq3"),         # no metadata: its while
+    ("reshape.11", "cada.eq3"),                      # a compiler's name: operand
 ])
 def test_op_scopes_innermost_phase(op_map, name, scope):
     assert op_map[name] == scope
@@ -144,3 +146,16 @@ def test_phase_ms_per_step_and_none_cases(op_map, monkeypatch):
     # no device trace (a CPU run): nothing, and no rebuild
     assert scopes.phase_ms(_View(summary=False), "cada.eq3") is None
     assert len(built) == 2
+
+
+def test_abstract_takes_one_sharding_or_a_tree_of_them():
+    jax = pytest.importorskip("jax")
+    from jax.sharding import SingleDeviceSharding
+    one = SingleDeviceSharding(jax.devices()[0])
+    tree = {"a": jax.numpy.zeros((2, 3)), "b": (jax.numpy.ones(4, "int32"),
+                                                None)}
+    for shardings in (one, {"a": one, "b": (one, None)}):
+        got = scopes._abstract(tree, shardings)
+        assert jax.tree.structure(got) == jax.tree.structure(tree)
+        for x, y in zip(jax.tree.leaves(got), jax.tree.leaves(tree)):
+            assert (x.shape, x.dtype, x.sharding) == (y.shape, y.dtype, one)

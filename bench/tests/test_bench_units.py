@@ -14,6 +14,8 @@ sys.path.insert(0, str(ROOT))
 from bench import compare, feed, flops, spec, traces  # noqa: E402
 
 RECORDED = Path(__file__).resolve().parent / "data" / "trace_small.json.gz"
+# four steps of the four-chip cell's window, traced on four v5e chips
+RECORDED_4 = RECORDED.with_name("trace_dp4.json.gz")
 
 
 # ------------------------------------------------------------------ traces
@@ -78,6 +80,26 @@ def test_exposed_collective_time():
     assert traces.exposed_collective_s(SYNTH) == pytest.approx(15e-9)
 
 
+def _traced_view(trace, steps):
+    cell = spec.load_cell("stablelm2-chip.cada2-dp4")
+    return type("V", (), {"trace": trace, "steps": steps,
+                          "summary": traces.summarize(trace)})(), cell
+
+
+def test_exposed_collective_ms_reads_per_step():
+    view, cell = _traced_view(SYNTH, 3)
+    read = spec.metric_reader(cell, "exposed_collective_ms")
+    assert read(view) == pytest.approx(15e-9 / 3 * 1e3)
+
+
+def test_exposed_collective_ms_is_null_without_a_collective():
+    bare = {"devices": {d: [e for e in evs if not traces.is_collective(e)]
+                        for d, evs in SYNTH["devices"].items()},
+            "host": SYNTH["host"]}
+    view, cell = _traced_view(bare, 3)
+    assert spec.metric_reader(cell, "exposed_collective_ms")(view) is None
+
+
 def test_gap_attribution_and_top_ops():
     s = traces.summarize(SYNTH)
     # dev0 gaps: 200..220 (prep), 241..280 (wait); dev1 (the while op
@@ -108,6 +130,29 @@ def test_recorded_trace_reduces():
         assert calls == steps and 0 < secs < s.window_s
     assert all(name.startswith("bench.") or name == "none"
                for name, _ in s.idle_gaps)
+
+
+@pytest.mark.skipif(not RECORDED.exists(), reason="no recorded trace")
+def test_recorded_one_chip_trace_reads_no_collective():
+    tr = traces.load_plain(str(RECORDED))
+    steps = sum(1 for e in tr["host"] if e[0] == "bench.dispatch")
+    view, cell = _traced_view(tr, steps)
+    assert spec.metric_reader(cell, "exposed_collective_ms")(view) is None
+
+
+def test_recorded_four_chip_trace_reduces():
+    tr = traces.load_plain(str(RECORDED_4))
+    assert len(tr["devices"]) == 4
+    s = traces.summarize(tr)
+    assert 0 < s.busy_s <= s.window_s
+    steps = sum(1 for e in tr["host"] if e[0] == "bench.dispatch")
+    for pattern in (r"fused_amsgrad_flat(\.\d+)?$",
+                    r"batched_diff_sq_norm(\.\d+)?$"):
+        secs, calls = traces.op_calls(tr, pattern)
+        assert calls == 4 * steps and 0 < secs < 4 * s.window_s
+    view, cell = _traced_view(tr, steps)
+    exposed = spec.metric_reader(cell, "exposed_collective_ms")(view)
+    assert 0 < exposed < s.window_s / steps * 1e3
 
 
 # ------------------------------------------------------------------ counts
@@ -225,6 +270,16 @@ def test_config_reduced_lists_every_changed_key():
         changed = [k for k, _ in cfg["reduced"]] + list(
             cfg.get("departures", {}))
         assert sorted(changed) == sorted(c["reduced"])
+
+
+def test_program_runs_one_worker_per_chip():
+    import dataclasses
+    from bench.harness import Program
+    cell = spec.load_cell("stablelm2-chip.cada2-dp4")
+    assert cell.chips == cell.workers == 4
+    two = dataclasses.replace(cell, traffic={**cell.traffic, "workers": 2})
+    with pytest.raises(ValueError, match="one worker per chip"):
+        Program(two, [])
 
 
 def test_unknown_peaks_are_an_error():
