@@ -49,11 +49,12 @@ import numpy as np
 from repro.core.quantize import topk_count, topk_threshold_mask
 from repro.kernels import ops as kops
 
-# Minimal flat-buffer alignment. The Pallas wrappers in kernels/ops.py
-# re-pad to whole kernel blocks on demand, so the layout itself stays lean:
-# on CPU a (M, n_flat) plane carries almost no padding waste even for toy
-# models (logreg: 46 -> 48), while TPU kernels see block-aligned buffers
-# after the wrapper's pad.
+# Minimal flat-buffer alignment, so the layout itself stays lean: on CPU a
+# (M, n_flat) plane carries almost no padding waste even for toy models
+# (logreg: 46 -> 48). On the TPU the (M, n_flat) plane kernels read the
+# planes at this width and mask their partial last block; only the
+# single-plane wrappers in kernels/ops.py (the AMSGrad update,
+# ``diff_sq_norm_flat``) still pad to whole kernel blocks.
 PAD_ALIGN = 8
 
 

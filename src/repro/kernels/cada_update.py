@@ -10,6 +10,8 @@ scalar reductions accumulated in fp32.
 TPU adaptation notes:
   * parameters are flattened and tiled into (BLOCK_ROWS, 128) VMEM blocks —
     lane dim 128, sublane a multiple of 8, so the VPU is fully utilized;
+    the (M, n) plane kernels instead read (rows, cols) blocks of the plane
+    in its own layout, so a few-row plane is never relayouted for them;
   * each block's partial sum is reduced to a scalar and added to an fp32
     accumulator that lives in SMEM (Mosaic cannot store scalars to VMEM):
     a whole-array (1, 1) output for the single-plane kernels, a whole-array
@@ -117,74 +119,83 @@ def fused_amsgrad_flat(theta, h, vhat, grad, lr, *, b1=0.9, b2=0.999,
     return (t_new.reshape(n), h_new.reshape(n), vh_new.reshape(n), sq[0, 0])
 
 
-def _batched_diff_sq_kernel(a_ref, b_ref, out_ref):
-    """Partial Σ_j (a_mj − b_mj)² for ONE worker row, accumulated across the
-    inner (sequential) block grid axis — all M CADA rule LHS norms in a
-    single pass over the two (M, n) planes."""
-    i = pl.program_id(0)
-    d = a_ref[...].astype(jnp.float32) - b_ref[...].astype(jnp.float32)
-    blk = jnp.sum(d * d)
+LHS_COLS = 1 << 16   # lanes of one rule-LHS column block (256 KiB of a row)
 
-    @pl.when(pl.program_id(1) == 0)
+
+def _batched_diff_sq_kernel(*refs, n: int, cols: int):
+    """Partial Σ_j (a_mj − b_mj)² (Σ_j a_mj² with one operand) for every
+    row of one (rows, cols) block of the (M, n) planes, added to each
+    row's entry of the (M,) SMEM accumulator across the inner
+    (sequential) column axis. The lanes at or past n in the partial last
+    column block hold whatever lies beyond the plane, NaN included, so
+    they are selected away before squaring."""
+    *ins, out_ref = refs
+    rows = ins[0].shape[0]
+    i, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(j == 0)
     def _init():
-        out_ref[i] = 0.0
+        for r in range(rows):
+            out_ref[i * rows + r] = 0.0
 
-    out_ref[i] += blk
+    def accumulate(tail: bool):
+        d = ins[0][...].astype(jnp.float32)
+        if len(ins) == 2:
+            d = d - ins[1][...].astype(jnp.float32)
+        if tail:
+            col = j * cols + jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
+            d = jnp.where(col < n, d, 0.0)
+        sq = d * d
+        for r in range(rows):
+            out_ref[i * rows + r] += jnp.sum(sq[r:r + 1, :])
+
+    if n % cols:
+        last = pl.num_programs(1) - 1
+        pl.when(j < last)(lambda: accumulate(False))
+        pl.when(j == last)(lambda: accumulate(True))
+    else:
+        accumulate(False)
+
+
+def _batched_row_norms(*planes, interpret):
+    """(M,) per-row squared norms of ``planes[0] − planes[1]`` (or of
+    ``planes[0]`` alone) over (M, n) planes of any width, read in their
+    own layout.
+
+    Blocks are (rows, cols): ``rows`` is all M up to a sublane tile (8),
+    and ``cols`` is a fixed ``LHS_COLS`` (fewer for a narrower plane), so
+    the order in which a row is summed depends on n alone — never on the
+    rows that share the call, which keeps the rows of a (C, n) cohort
+    plane bit-identical to the same rows of the dense (M, n) pass. The
+    grid is (row blocks, column blocks), the column axis innermost; a
+    partial last row block's extra accumulator entries are sliced off.
+    """
+    m, n = planes[0].shape
+    rows = min(m, 8)
+    cols = min(LHS_COLS, pl.cdiv(n, LANES) * LANES)
+    grid = (pl.cdiv(m, rows), pl.cdiv(n, cols))
+    spec = pl.BlockSpec((rows, cols), lambda i, j: (i, j))
+    out = pl.pallas_call(
+        partial(_batched_diff_sq_kernel, n=n, cols=cols),
+        grid=grid,
+        in_specs=[spec] * len(planes),
+        out_specs=_SMEM,
+        out_shape=jax.ShapeDtypeStruct((grid[0] * rows,), jnp.float32),
+        compiler_params=_sequential(2),
+        interpret=interpret,
+    )(*planes)
+    return out if out.shape[0] == m else out[:m]
 
 
 def batched_diff_sq_norm_flat(a, b, *, interpret=False):
-    """(M,) per-worker ||a_m − b_m||² over (M, n) pre-flattened planes.
-
-    The grid is (M, n/BLOCK) with the block axis innermost: the TPU grid is
-    sequential, so each worker's entry of the (M,) SMEM accumulator is
-    initialized at its first block and revisited — the same pattern as the
-    unbatched kernels, just with a second grid axis for the worker rows.
-    """
-    m, n = a.shape
-    assert n % BLOCK == 0, f"flat width {n} not a multiple of {BLOCK}"
-    nb = n // BLOCK
-    shape3d = (m, nb * BLOCK_ROWS, LANES)
-    spec = pl.BlockSpec((None, BLOCK_ROWS, LANES), lambda i, j: (i, j, 0))
-    return pl.pallas_call(
-        _batched_diff_sq_kernel,
-        grid=(m, nb),
-        in_specs=[spec, spec],
-        out_specs=_SMEM,
-        out_shape=jax.ShapeDtypeStruct((m,), jnp.float32),
-        compiler_params=_sequential(2),
-        interpret=interpret,
-    )(a.reshape(shape3d), b.reshape(shape3d))
-
-
-def _batched_sq_kernel(a_ref, out_ref):
-    """Partial Σ_j a_mj² for one worker row (single-operand variant)."""
-    i = pl.program_id(0)
-    v = a_ref[...].astype(jnp.float32)
-    blk = jnp.sum(v * v)
-
-    @pl.when(pl.program_id(1) == 0)
-    def _init():
-        out_ref[i] = 0.0
-
-    out_ref[i] += blk
+    """(M,) per-worker ||a_m − b_m||² over two (M, n) planes of any width,
+    in one pass (see :func:`_batched_row_norms`)."""
+    return _batched_row_norms(a, b, interpret=interpret)
 
 
 def batched_sq_norm_flat(a, *, interpret=False):
-    """(M,) per-worker ||a_m||² over an (M, n) pre-flattened plane."""
-    m, n = a.shape
-    assert n % BLOCK == 0, f"flat width {n} not a multiple of {BLOCK}"
-    nb = n // BLOCK
-    shape3d = (m, nb * BLOCK_ROWS, LANES)
-    spec = pl.BlockSpec((None, BLOCK_ROWS, LANES), lambda i, j: (i, j, 0))
-    return pl.pallas_call(
-        _batched_sq_kernel,
-        grid=(m, nb),
-        in_specs=[spec],
-        out_specs=_SMEM,
-        out_shape=jax.ShapeDtypeStruct((m,), jnp.float32),
-        compiler_params=_sequential(2),
-        interpret=interpret,
-    )(a.reshape(shape3d))
+    """(M,) per-worker ||a_m||² over an (M, n) plane of any width."""
+    return _batched_row_norms(a, interpret=interpret)
 
 
 def _diff_sq_kernel(a_ref, b_ref, out_ref):

@@ -11,10 +11,15 @@ Kernel-mode routing (the ``interpret`` flag on every flat op):
     tiling);
   * ``False`` — force the compiled Pallas kernel.
 
-The wrappers also own the BLOCK padding: arbitrary flat lengths are padded
-with zeros up to whole kernel blocks and sliced back, so every pytree —
-logreg through the LM path — takes the fused route (zero-padded gradients
-leave zero moments and a zero update, so reductions are unaffected).
+The single-plane wrappers (``fused_amsgrad_flat``, ``diff_sq_norm_flat``)
+also own the BLOCK padding: arbitrary flat lengths are padded with zeros up
+to whole kernel blocks and sliced back, so every pytree — logreg through
+the LM path — takes the fused route (zero-padded gradients leave zero
+moments and a zero update, so reductions are unaffected). The (M, n)
+plane kernels (``batched_diff_sq_norm``, ``batched_sq_norm``,
+``eq3_row_mean``) need no padding: they read (rows, cols) blocks of the
+plane as it lies and mask the partial last column block, so no copy of a
+plane is made around them.
 
 Sharded flat planes (the ``shard`` flag on the flat ops, a static
 ``distributed.sharding.FlatSharding``): the same kernels run SHARD-LOCAL
@@ -59,12 +64,6 @@ def _pad_flat(arrs, block=_cu.BLOCK):
     if pad == 0:
         return arrs, n
     return [jnp.pad(a, ((0, pad),)) for a in arrs], n
-
-
-def _pad_plane(a, block=_cu.BLOCK):
-    """Zero-pad the flat axis of an (M, n) plane to whole blocks."""
-    pad = (-a.shape[1]) % block
-    return jnp.pad(a, ((0, 0), (0, pad))) if pad else a
 
 
 def _shard_map(f, shard, in_specs, out_specs):
@@ -181,6 +180,9 @@ def batched_diff_sq_norm(a, b, *, interpret=None, shard=None):
     device sweeps only its own rows (the worker axis) and its slice of the
     plane's columns, finishing the per-row partials with one psum over the
     column axes. Rows stay whole per device otherwise.
+
+    On the TPU the kernel reads both planes in their own layout, at any
+    width: no pad and no relayout copy of a plane.
     """
     if shard is not None:
         from jax.sharding import PartitionSpec as P
@@ -197,8 +199,7 @@ def batched_diff_sq_norm(a, b, *, interpret=None, shard=None):
     if not pallas:
         d = a.astype(jnp.float32) - b.astype(jnp.float32)
         return jnp.sum(d * d, axis=1)
-    ap, bp = (_pad_plane(x) for x in (a, b))
-    return _cu.batched_diff_sq_norm_flat(ap, bp, interpret=interpret)
+    return _cu.batched_diff_sq_norm_flat(a, b, interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("interpret", "shard"))
@@ -219,7 +220,7 @@ def batched_sq_norm(a, *, interpret=None, shard=None):
     if not pallas:
         v = a.astype(jnp.float32)
         return jnp.sum(v * v, axis=1)
-    return _cu.batched_sq_norm_flat(_pad_plane(a), interpret=interpret)
+    return _cu.batched_sq_norm_flat(a, interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("chunk", "dblk", "interpret"))

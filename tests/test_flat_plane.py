@@ -103,8 +103,9 @@ def test_batched_diff_sq_norm_kernel_vs_oracle(rng):
 
 
 def test_batched_wrappers_pad_arbitrary_widths(rng):
-    """kernels/ops.py wrappers accept any flat width (satellite: no
-    n % BLOCK restriction) on both the jnp and interpret-Pallas routes."""
+    """kernels/ops.py wrappers accept any flat width (no n % BLOCK
+    restriction) on both the jnp and interpret-Pallas routes: the kernel
+    masks the partial last column block, with no pad in the wrapper."""
     m, n = 4, 1234
     a = jnp.asarray(rng.normal(size=(m, n)), jnp.float32)
     b = jnp.asarray(rng.normal(size=(m, n)), jnp.float32)
@@ -112,6 +113,50 @@ def test_batched_wrappers_pad_arbitrary_widths(rng):
     for interpret in (None, True):
         got = kops.batched_diff_sq_norm(a, b, interpret=interpret)
         np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4)
+
+
+# Widths that are a multiple of neither the LHS column block nor 128, and
+# one over several column blocks; M = 9 spans two row blocks.
+RAGGED_WIDTHS = (1234, 2 * _cu.LHS_COLS + 77)
+
+
+@pytest.mark.parametrize("m", [1, 4, 9])
+@pytest.mark.parametrize("n", RAGGED_WIDTHS)
+def test_batched_norm_kernels_read_ragged_planes(m, n):
+    """The batched LHS kernels (interpret mode) read an (M, n) plane of
+    any width as it lies and match the fp32 oracle. Interpret mode fills
+    the lanes past the plane with NaN, so a finite match also shows the
+    partial last column block is masked before squaring."""
+    rng = np.random.default_rng(m * n)   # leaves the session stream as it is
+    a = jnp.asarray(rng.normal(size=(m, n)), jnp.float32)
+    b = jnp.asarray(rng.normal(size=(m, n)), jnp.float32)
+    got = _cu.batched_diff_sq_norm_flat(a, b, interpret=True)
+    assert got.shape == (m,)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(per_worker_sq_norm({"x": a - b})),
+                               rtol=1e-5)
+    got1 = _cu.batched_sq_norm_flat(a, interpret=True)
+    np.testing.assert_allclose(np.asarray(got1),
+                               np.asarray(per_worker_sq_norm({"x": a})),
+                               rtol=1e-5)
+
+
+@pytest.mark.parametrize("rows", [[8], [0, 3, 8], [1, 2, 3, 4, 5, 6, 7]])
+def test_batched_norm_cohort_rows_bit_identical(rows):
+    """Rows of a (C, n) cohort sub-plane give the same bits as the same
+    rows of the dense (M, n) pass: a row's sum order depends on n alone,
+    not on the rows that share its call or its row block."""
+    m, n = 9, _cu.LHS_COLS + 333
+    rng = np.random.default_rng(len(rows))
+    a = jnp.asarray(rng.normal(size=(m, n)), jnp.float32)
+    b = jnp.asarray(rng.normal(size=(m, n)), jnp.float32)
+    idx = np.asarray(rows)
+    for f, planes in ((_cu.batched_diff_sq_norm_flat, (a, b)),
+                      (_cu.batched_sq_norm_flat, (a,))):
+        dense = np.asarray(f(*planes, interpret=True))
+        cohort = np.asarray(f(*(x[idx] for x in planes), interpret=True))
+        np.testing.assert_array_equal(cohort.view(np.uint32),
+                                      dense[idx].view(np.uint32))
 
 
 def test_fused_amsgrad_flat_arbitrary_length(rng):

@@ -8,6 +8,8 @@ The topology is described inside a fixture, never while a module is
 imported: only one process at a time may load the TPU library, and it keeps
 it until it exits, so every test that needs it lives in this one file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -74,18 +76,37 @@ def test_diff_sq_norm_flat_compiles(one_chip, n_flat):
     assert "tpu_custom_call" in txt
 
 
+# An instruction that copies a plane, on its own or inside a fusion.
+_PLANE_COPY = re.compile(r"\b(?:pad|copy|slice)\(")
+
+
+def _assert_reads_planes_as_they_lie(txt):
+    """The module is the ``batched_diff_sq_norm`` / ``batched_sq_norm``
+    kernel on its parameters: no pad, relayout copy or slice of a plane
+    in front of it."""
+    assert re.search(r"%batched_(?:diff_)?sq_norm(?:\.\d+)? = .*"
+                     r"custom-call\(.*tpu_custom_call", txt)
+    assert not _PLANE_COPY.findall(txt)
+
+
 def test_batched_diff_sq_norm_flat_compiles(one_chip, n_flat):
-    a = _sds(one_chip, (M, n_flat))
-    txt = _compiled_text(
-        lambda x, y: ops.batched_diff_sq_norm(x, y, interpret=False), a, a)
-    assert "tpu_custom_call" in txt
+    """The rule LHS over the M=4 planes and over one shard-local row."""
+    for m in (M, 1):
+        a = _sds(one_chip, (m, n_flat))
+        txt = _compiled_text(
+            lambda x, y: ops.batched_diff_sq_norm(x, y, interpret=False),
+            a, a)
+        assert "tpu_custom_call" in txt
+        _assert_reads_planes_as_they_lie(txt)
 
 
 def test_batched_sq_norm_flat_compiles(one_chip, n_flat):
-    a = _sds(one_chip, (M, n_flat))
-    txt = _compiled_text(
-        lambda x: ops.batched_sq_norm(x, interpret=False), a)
-    assert "tpu_custom_call" in txt
+    for m in (M, 1):
+        a = _sds(one_chip, (m, n_flat))
+        txt = _compiled_text(
+            lambda x: ops.batched_sq_norm(x, interpret=False), a)
+        assert "tpu_custom_call" in txt
+        _assert_reads_planes_as_they_lie(txt)
 
 
 def test_eq3_row_mean_compiles_to_one_pass(one_chip, n_flat):
